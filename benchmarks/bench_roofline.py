@@ -33,11 +33,12 @@ import os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 out_dir = sys.argv[1]
 
-from repro.dist.compat import make_mesh
+import jax
+from jax.sharding import AxisType
 from repro.launch.dryrun import run_cell
 from repro.models.config import ShapeConfig
 
-mesh = make_mesh((2, 4), ("data", "model"), auto_axis_types=True)
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 train = ShapeConfig("mini_train", "train", 128, 8)
 decode = ShapeConfig("mini_decode", "decode", 256, 8)
 cells = [
@@ -60,6 +61,8 @@ def run(scale: float = 1.0) -> list[tuple[str, float, str]]:
     env["PYTHONPATH"] = (
         os.path.join(repo, "src") + os.pathsep + env.get("PYTHONPATH", "")
     )
+    # a CPU dry-run: the child must never take the accelerator from its parent
+    env["JAX_PLATFORMS"] = "cpu"
     with tempfile.TemporaryDirectory(prefix="roofline_mini_") as out_dir:
         proc = subprocess.run(
             [sys.executable, "-c", _MINI_SCRIPT, out_dir],

@@ -16,7 +16,6 @@ Modules
                and per-stage pipeline submeshes
 ``stragglers`` cross-host step-time reduction + slow-host detection
 ``pipeline``   GPipe forward + 1F1B training schedules, microbatch/stage plans
-``compat``     shims over jax API drift (``shard_map``, ``make_mesh``)
 
 Acting on what the reduction finds — rebalancing microbatch plans, evicting
 hosts, rebuilding meshes — is orchestrated by :mod:`repro.adapt`.

@@ -17,9 +17,7 @@ from collections.abc import Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-from .compat import make_mesh
+from jax.sharding import AxisType, Mesh
 
 __all__ = ["local_mesh", "default_axis_names", "pipeline_submeshes", "remove_host"]
 
@@ -65,7 +63,8 @@ def local_mesh(
             f"{len(devices)} are visible; set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={n_needed} for CPU dry-runs"
         )
-    return make_mesh(shape, names, devices=devices[:n_needed])
+    return jax.make_mesh(shape, names, devices=devices[:n_needed],
+                         axis_types=(AxisType.Auto,) * len(names))
 
 
 def remove_host(mesh: Mesh, index: int, axis: str | None = None) -> Mesh:
@@ -91,7 +90,7 @@ def remove_host(mesh: Mesh, index: int, axis: str | None = None) -> Mesh:
     if not 0 <= index < size:
         raise ValueError(f"slice {index} out of range [0, {size}) on axis {axis!r}")
     devices = np.delete(np.asarray(mesh.devices), index, axis=pos)
-    return Mesh(devices, names)
+    return Mesh(devices, names, axis_types=(AxisType.Auto,) * len(names))
 
 
 def pipeline_submeshes(mesh: Mesh, axis: str) -> list[Mesh]:
@@ -116,7 +115,9 @@ def pipeline_submeshes(mesh: Mesh, axis: str) -> list[Mesh]:
         stage_devices = np.take(devices, [i], axis=pos)
         if len(names) > 1:
             stage_devices = np.squeeze(stage_devices, axis=pos)
-            out.append(Mesh(stage_devices, names[:pos] + names[pos + 1:]))
+            stage_names = names[:pos] + names[pos + 1:]
         else:
-            out.append(Mesh(stage_devices, names))
+            stage_names = names
+        out.append(Mesh(stage_devices, stage_names,
+                        axis_types=(AxisType.Auto,) * len(stage_names)))
     return out

@@ -58,8 +58,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import shard_map
-
 __all__ = [
     "MicrobatchPlan",
     "PipelineStep",
@@ -410,8 +408,8 @@ def gpipe_forward(
 
     # P(axis) on the stage dimension leaves each device a contiguous
     # (n_stages // axis_size, ...) block of consecutive stages
-    fn = shard_map(
-        pipelined, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(), check=False
+    fn = jax.shard_map(
+        pipelined, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(), check_vma=False
     )
     return fn(stage_params, x)
 
@@ -794,13 +792,13 @@ class PipelineStep:
 
         carry_specs = (P(axis), P(axis), P(axis), P(axis), P(axis),
                        self.stage_spec, P(axis), P(axis))
-        smapped = shard_map(
+        smapped = jax.shard_map(
             shard_body,
             mesh=self.mesh,
             in_specs=(self.stage_spec, P(axis), P(), P(), P(), P(),
                       carry_specs, None, None),
             out_specs=carry_specs,
-            check=False,
+            check_vma=False,
         )
 
         @functools.partial(jax.jit, static_argnums=(7, 8))

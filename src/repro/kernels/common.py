@@ -1,4 +1,4 @@
-"""Shared Pallas utilities: platform dispatch + compiler-params shims."""
+"""Shared Pallas utilities: platform dispatch + compiler params."""
 
 from __future__ import annotations
 
@@ -12,9 +12,9 @@ __all__ = ["default_interpret", "tpu_compiler_params"]
 def default_interpret(interpret: bool | None) -> bool:
     """Pallas TPU kernels run in interpret mode on non-TPU backends.
 
-    This container is CPU-only: interpret=True executes the kernel body with
-    jnp semantics (correctness validation); on a real TPU the same code lowers
-    through Mosaic.
+    On a CPU backend interpret=True executes the kernel body with jnp
+    semantics (correctness validation); on a TPU the same code lowers through
+    Mosaic.
     """
     if interpret is not None:
         return interpret
@@ -27,5 +27,4 @@ def tpu_compiler_params(dimension_semantics: Sequence[str], interpret: bool):
         return None
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams")
-    return cls(dimension_semantics=tuple(dimension_semantics))
+    return pltpu.CompilerParams(dimension_semantics=tuple(dimension_semantics))

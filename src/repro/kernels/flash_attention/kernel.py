@@ -8,8 +8,11 @@ head_dim ≥ 128-multiples feeding the MXU; masks are built from
 ``broadcasted_iota`` (TPU requires ≥2D iota).
 
 Layout: q (B, H, S, hd); k/v (B, KV, T, hd) — the ops wrapper transposes from
-the model's (B, S, H, hd).  GQA is handled by indexing the KV head as
-``h // group`` in the BlockSpec index maps.  Causal and sliding-window masks
+the model's (B, S, H, hd).  The per-row softmax statistics ``lse`` and
+``delta`` are kept as (B, H, 1, S) so that their (1, block_q) tiles lie along
+the lane axis: Mosaic requires the last two block dims to be (8, 128)-aligned
+or whole, and a (block_q,) trailing block is neither.  GQA is handled by
+indexing the KV head as ``h // group`` in the BlockSpec index maps.  Causal and sliding-window masks
 are supported; fully-masked K blocks are skipped with ``pl.when``.
 """
 
@@ -87,7 +90,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     def _finish():
         l = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_scr[...] + jnp.log(l))[:, 0]
+        lse_ref[0, 0] = (m_scr[...] + jnp.log(l)).T  # (bq, 1) -> (1, bq)
 
 
 def flash_fwd(
@@ -101,7 +104,7 @@ def flash_fwd(
     block_k: int,
     interpret: bool,
 ):
-    """q: (B,H,S,hd); k/v: (B,KV,T,hd). Returns (out (B,H,S,hd), lse (B,H,S))."""
+    """q: (B,H,S,hd); k/v: (B,KV,T,hd). Returns (out (B,H,S,hd), lse (B,H,1,S))."""
     b, h, s, hd = q.shape
     kv, t = k.shape[1], k.shape[2]
     group = h // kv
@@ -122,11 +125,11 @@ def flash_fwd(
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, hd), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda bi, hi, qi, ki: (bi, hi, qi)),
+            pl.BlockSpec((1, 1, 1, block_q), lambda bi, hi, qi, ki: (bi, hi, 0, qi)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, s, hd), q.dtype),
-            jax.ShapeDtypeStruct((b, h, s), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -159,8 +162,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
         kb = k_ref[0, 0].astype(jnp.float32)
         vb = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
+        lse = lse_ref[0, 0].T  # (1, bq) -> (bq, 1)
+        delta = delta_ref[0, 0].T
         s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32) * scale
         s += _mask((block_q, block_k), iq * block_q, ik * block_k, causal, window)
         p = jnp.exp(s - lse)
@@ -190,8 +193,8 @@ def flash_bwd_dq(q, k, v, do, lse, delta, *, causal, window, block_q, block_k, i
             pl.BlockSpec((1, 1, block_k, hd), lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
             pl.BlockSpec((1, 1, block_k, hd), lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
             pl.BlockSpec((1, 1, block_q, hd), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda bi, hi, qi, ki: (bi, hi, qi)),
-            pl.BlockSpec((1, 1, block_q), lambda bi, hi, qi, ki: (bi, hi, qi)),
+            pl.BlockSpec((1, 1, 1, block_q), lambda bi, hi, qi, ki: (bi, hi, 0, qi)),
+            pl.BlockSpec((1, 1, 1, block_q), lambda bi, hi, qi, ki: (bi, hi, 0, qi)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, hd), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s, hd), q.dtype),
@@ -223,8 +226,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
         kb = k_ref[0, 0].astype(jnp.float32)
         vb = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
+        lse = lse_ref[0, 0].T  # (1, bq) -> (bq, 1)
+        delta = delta_ref[0, 0].T
         s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32) * scale
         s += _mask((block_q, block_k), iq * block_q, ik * block_k, causal, window)
         p = jnp.exp(s - lse)  # (bq, bk)
@@ -257,8 +260,8 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, window, block_q, block_k, 
             pl.BlockSpec((1, 1, block_k, hd), lambda bi, hi, ki, qi: (bi, hi // group, ki, 0)),
             pl.BlockSpec((1, 1, block_k, hd), lambda bi, hi, ki, qi: (bi, hi // group, ki, 0)),
             pl.BlockSpec((1, 1, block_q, hd), lambda bi, hi, ki, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda bi, hi, ki, qi: (bi, hi, qi)),
-            pl.BlockSpec((1, 1, block_q), lambda bi, hi, ki, qi: (bi, hi, qi)),
+            pl.BlockSpec((1, 1, 1, block_q), lambda bi, hi, ki, qi: (bi, hi, 0, qi)),
+            pl.BlockSpec((1, 1, 1, block_q), lambda bi, hi, ki, qi: (bi, hi, 0, qi)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, hd), lambda bi, hi, ki, qi: (bi, hi, ki, 0)),

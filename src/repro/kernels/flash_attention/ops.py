@@ -3,7 +3,8 @@
 Public entry ``flash_attention(q, k, v, causal=..., window=...)`` takes the
 model layout (B, S, H, hd) / (B, T, KV, hd), transposes to kernel layout,
 runs the Pallas forward, and differentiates through the dq/dkv Pallas kernels.
-Falls back to the chunked pure-JAX implementation when shapes do not tile.
+Block sizes are shrunk to a tiling the TPU accepts; a sequence length that has
+none raises instead of silently running another attention implementation.
 """
 
 from __future__ import annotations
@@ -14,15 +15,27 @@ import jax
 import jax.numpy as jnp
 
 from . import kernel as K
-from ...models.attention import chunked_attention
 from ..common import default_interpret
 
 
 __all__ = ["flash_attention"]
 
+_LANES = 128
 
-def _tiles(s: int, block: int) -> bool:
-    return s % block == 0 and s >= block
+
+def _pick_block(n: int, block: int) -> int:
+    """The tile for a length-``n`` sequence axis: the whole axis when it fits
+    in ``block``, else the largest multiple of 128 lanes ≤ ``block`` dividing
+    ``n`` (Mosaic needs the lse/delta tiles (8, 128)-aligned or whole)."""
+    if n <= block:
+        return n
+    for cand in range(block - block % _LANES, 0, -_LANES):
+        if n % cand == 0:
+            return cand
+    raise ValueError(
+        f"flash attention cannot tile a sequence of length {n} with blocks of at "
+        f"most {block}: pad it to a multiple of {_LANES}, or use attn_impl='chunked'"
+    )
 
 
 @functools.partial(
@@ -48,7 +61,7 @@ def _flash_bwd_rule(causal, window, block_q, block_k, interpret, res, do):
     q, k, v, out, lse = res
     kv = k.shape[1]
     group = q.shape[1] // kv
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)[:, :, None]
     dq = K.flash_bwd_dq(
         q, k, v, do, lse, delta, causal=causal, window=window,
         block_q=block_q, block_k=block_k, interpret=interpret,
@@ -82,8 +95,7 @@ def flash_attention(
     b, s, h, hd = q.shape
     t = k.shape[1]
     interpret = default_interpret(interpret)
-    if not (_tiles(s, block_q) and _tiles(t, block_k)):
-        return chunked_attention(q, k, v, causal=causal, window=window)
+    block_q, block_k = _pick_block(s, block_q), _pick_block(t, block_k)
     qt = jnp.swapaxes(q, 1, 2)  # (B,H,S,hd)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)

@@ -2,9 +2,12 @@
 
 grid = (B, n_d_blocks, n_chunks); the channel dimension is blocked (parallel)
 and chunks advance sequentially ("arbitrary") with the (1, d_block) state held
-in VMEM scratch.  Token loop inside the chunk is a fori_loop over rows of the
-(chunk, d_block) tile — elementwise vector work; the op is bandwidth-bound and
-streams a/b tiles from HBM exactly once.
+in VMEM scratch.  Each chunk's a/b tiles are staged into f32 VMEM scratch
+once; the token loop is a fori_loop that reads and writes one (1, d_block) row
+per step through those refs (Mosaic has no ``dynamic_slice`` of a loaded
+value, and a one-row dynamic slice of a packed bf16 tile is not 8-aligned) —
+elementwise vector work; the op is bandwidth-bound and streams a/b tiles from
+HBM exactly once.
 """
 
 from __future__ import annotations
@@ -21,29 +24,26 @@ from ..common import default_interpret, tpu_compiler_params
 __all__ = ["rglru_pallas"]
 
 
-def _rglru_kernel(a_ref, b_ref, h0_ref, y_ref, hout_ref, state_scr, *, chunk, n_chunks):
+def _rglru_kernel(a_ref, b_ref, h0_ref, y_ref, hout_ref, state_scr, a_scr, b_scr, y_scr,
+                  *, chunk, n_chunks):
     ic = pl.program_id(2)
 
     @pl.when(ic == 0)
     def _init():
         state_scr[...] = h0_ref[0].astype(jnp.float32)  # (1, D)
 
-    a = a_ref[0].astype(jnp.float32)  # (C, D)
-    b = b_ref[0].astype(jnp.float32)
+    a_scr[...] = a_ref[0].astype(jnp.float32)  # (C, D)
+    b_scr[...] = b_ref[0].astype(jnp.float32)
 
-    def step(t, carry):
-        h, y = carry  # h: (1, D); y: (C, D)
-        a_t = jax.lax.dynamic_slice_in_dim(a, t, 1, 0)
-        b_t = jax.lax.dynamic_slice_in_dim(b, t, 1, 0)
-        h = a_t * h + b_t
-        y = jax.lax.dynamic_update_slice_in_dim(y, h, t, 0)
-        return h, y
+    def step(t, h):  # h: (1, D)
+        row = pl.ds(t, 1)
+        h = a_scr[row, :] * h + b_scr[row, :]
+        y_scr[row, :] = h
+        return h
 
-    h, y = jax.lax.fori_loop(
-        0, chunk, step, (state_scr[...], jnp.zeros_like(y_ref[0], jnp.float32))
-    )
+    h = jax.lax.fori_loop(0, chunk, step, state_scr[...])
     state_scr[...] = h
-    y_ref[0] = y.astype(y_ref.dtype)
+    y_ref[0] = y_scr[...].astype(y_ref.dtype)
 
     @pl.when(ic == n_chunks - 1)
     def _finish():
@@ -87,7 +87,8 @@ def rglru_pallas(
             jax.ShapeDtypeStruct((bsz, s, d), a.dtype),
             jax.ShapeDtypeStruct((bsz, 1, d), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((1, d_block), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, d_block), jnp.float32)]
+        + [pltpu.VMEM((chunk, d_block), jnp.float32)] * 3,
         compiler_params=tpu_compiler_params(
             ("parallel", "parallel", "arbitrary"), interpret
         ),

@@ -5,9 +5,12 @@ in registers/shared memory of one CTA and scan tokens sequentially.  On TPU we
 keep the state in **VMEM scratch** that persists across the sequential chunk
 dimension of the grid: grid = (B·H, n_chunks) with semantics
 ("parallel", "arbitrary"); each step streams a (chunk, K) tile of r/k/w and a
-(chunk, V) tile of v from HBM and runs the token recurrence with VMEM-resident
-state.  The recurrence itself is vector-unit work (elementwise + small outer
-products); the op is HBM-bandwidth-bound, which is exactly why streaming
+(chunk, V) tile of v from HBM, stages the tiles into f32 VMEM scratch, and
+runs the token recurrence with VMEM-resident state, reading and writing one
+token row per step through the scratch refs (Mosaic has no ``dynamic_slice``
+of a loaded value, and a one-row slice of a packed bf16 tile is not
+8-aligned).  The recurrence itself is vector-unit work (elementwise + small
+outer products); the op is HBM-bandwidth-bound, which is exactly why streaming
 chunks with a resident state is the right TPU shape.
 """
 
@@ -26,36 +29,30 @@ __all__ = ["wkv6_pallas"]
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
-                state_scr, *, chunk, n_chunks):
+                state_scr, r_scr, k_scr, v_scr, w_scr, y_scr, *, chunk, n_chunks):
     ic = pl.program_id(1)
 
     @pl.when(ic == 0)
     def _init():
         state_scr[...] = s0_ref[0].astype(jnp.float32)
 
-    r = r_ref[0].astype(jnp.float32)  # (C, K)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)  # (C, V)
-    w = w_ref[0].astype(jnp.float32)
+    for ref, scr in ((r_ref, r_scr), (k_ref, k_scr), (v_ref, v_scr), (w_ref, w_scr)):
+        scr[...] = ref[0].astype(jnp.float32)  # (C, K) / (C, V)
     u = u_ref[0].astype(jnp.float32)  # (1, K)
 
-    def step(t, carry):
-        S, y = carry  # S: (K, V); y: (C, V)
-        r_t = jax.lax.dynamic_slice_in_dim(r, t, 1, 0)  # (1, K)
-        k_t = jax.lax.dynamic_slice_in_dim(k, t, 1, 0)
-        v_t = jax.lax.dynamic_slice_in_dim(v, t, 1, 0)  # (1, V)
-        w_t = jax.lax.dynamic_slice_in_dim(w, t, 1, 0)
+    def step(t, S):  # S: (K, V)
+        row = pl.ds(t, 1)
+        r_t = r_scr[row, :]  # (1, K)
+        k_t = k_scr[row, :]
+        v_t = v_scr[row, :]  # (1, V)
+        w_t = w_scr[row, :]
         kv = k_t.T * v_t  # (K, V) outer product
-        y_t = jnp.dot(r_t, S + u.T * kv, preferred_element_type=jnp.float32)
-        y = jax.lax.dynamic_update_slice_in_dim(y, y_t, t, 0)
-        S = w_t.T * S + kv
-        return S, y
+        y_scr[row, :] = jnp.dot(r_t, S + u.T * kv, preferred_element_type=jnp.float32)
+        return w_t.T * S + kv
 
-    S, y = jax.lax.fori_loop(
-        0, chunk, step, (state_scr[...], jnp.zeros_like(y_ref[0], jnp.float32))
-    )
+    S = jax.lax.fori_loop(0, chunk, step, state_scr[...])
     state_scr[...] = S
-    y_ref[0] = y.astype(y_ref.dtype)
+    y_ref[0] = y_scr[...].astype(y_ref.dtype)
 
     @pl.when(ic == n_chunks - 1)
     def _finish():
@@ -111,7 +108,14 @@ def wkv6_pallas(
             jax.ShapeDtypeStruct((b * h, s, dv), r.dtype),
             jax.ShapeDtypeStruct((b * h, dk, dv), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((dk, dv), jnp.float32),
+            pltpu.VMEM((chunk, dk), jnp.float32),
+            pltpu.VMEM((chunk, dk), jnp.float32),
+            pltpu.VMEM((chunk, dv), jnp.float32),
+            pltpu.VMEM((chunk, dk), jnp.float32),
+            pltpu.VMEM((chunk, dv), jnp.float32),
+        ],
         compiler_params=tpu_compiler_params(("parallel", "arbitrary"), interpret),
         interpret=interpret,
     )(rf, kf, vf, wf, uf, s0)

@@ -61,9 +61,7 @@ def _mem_analysis(compiled) -> dict[str, Any]:
 
 def _cost_analysis(compiled) -> dict[str, float]:
     try:
-        from ..dist.compat import cost_analysis
-
-        cost = cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         return {k: float(v) for k, v in cost.items() if isinstance(v, (int, float))}
     except Exception as exc:  # noqa: BLE001
         return {"error_msg": 0.0, "_error": str(exc)}  # type: ignore[dict-item]

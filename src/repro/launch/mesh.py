@@ -10,7 +10,8 @@ int8-compressed, optim/compression.py).
 
 from __future__ import annotations
 
-from ..dist.compat import make_mesh
+import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "POD_SHAPE", "MULTI_POD_SHAPE"]
 
@@ -21,4 +22,4 @@ MULTI_POD_SHAPE = (2, 16, 16)
 def make_production_mesh(*, multi_pod: bool = False):
     shape = MULTI_POD_SHAPE if multi_pod else POD_SHAPE
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, auto_axis_types=True)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

@@ -23,9 +23,11 @@ import numpy as np
 
 from ..configs import ARCH_IDS, get_smoke_config
 from ..models import model as M
+from ..models.config import ArchConfig
 from ..monitor import MetricsExporter, serving_payload
 from ..serving import Request, ServeSession, ServiceLevel
 from ..timing import TimingSession
+from .cache import configure_compile_cache
 
 __all__ = ["main", "run_serving"]
 
@@ -43,16 +45,18 @@ def run_serving(
     arrival_rate: float | None = None,
     seed: int = 0,
     session: TimingSession | None = None,
+    cfg: ArchConfig | None = None,
 ) -> ServeSession:
     """Build a :class:`~repro.serving.ServeSession` and serve ``n_requests``.
 
     With ``arrival_rate`` (requests/second) the submissions follow an
     open-loop Poisson process driven against the wall clock — the engine keeps
     decoding in-flight requests between arrivals; otherwise everything is
-    submitted upfront and drained.  Returns the engine (stats, request rows,
-    and its control loop's decision log attached).
+    submitted upfront and drained.  ``cfg`` overrides the smoke config of
+    ``arch`` (e.g. a published-width config cut in depth).  Returns the engine
+    (stats, request rows, and its control loop's decision log attached).
     """
-    cfg = get_smoke_config(arch)
+    cfg = cfg if cfg is not None else get_smoke_config(arch)
     params = M.init_params(cfg, jax.random.PRNGKey(seed))
     rng = np.random.default_rng(seed)
     engine = ServeSession(
@@ -109,6 +113,7 @@ def main(argv=None) -> int:
                     help="write the final Prometheus exposition here "
                          "(textfile-collector scrape path)")
     args = ap.parse_args(argv)
+    configure_compile_cache()
     sess = TimingSession()
     with sess:
         engine = run_serving(

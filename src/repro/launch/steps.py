@@ -10,6 +10,7 @@ lowers ``train_step``.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any
 
@@ -392,6 +393,31 @@ def make_transformer_pipeline_train_step(
     p_abs = M.abstract_params(cfg)
     o_abs = jax.eval_shape(lambda p: init_opt_state(opt_cfg, p), p_abs)
 
+    # state placement: the scanned stack (and its optimizer state) is split
+    # over the pipeline axis along its unit dimension, so each device keeps
+    # about its own stage's share; embed, norm and head leaves are replicated
+    replicated = NamedSharding(mesh, P())
+    stage_split = NamedSharding(mesh, P(axis))
+    n_stages = mesh.shape[axis]
+
+    def placement(path, sds):
+        in_stack = any(getattr(k, "key", None) == "scan" for k in path)
+        return stage_split if in_stack and sds.shape[0] % n_stages == 0 else replicated
+
+    p_shard = jax.tree_util.tree_map_with_path(placement, p_abs)
+    o_shard = jax.tree_util.tree_map_with_path(placement, o_abs)
+
+    @functools.partial(
+        jax.jit, out_shardings=(p_shard, o_shard, None), donate_argnums=(0, 1)
+    )
+    def apply_update(params, opt_state, grads):
+        lr = warmup_cosine(
+            opt_state["step"], peak_lr=peak_lr, warmup_steps=warmup_steps,
+            total_steps=total_steps,
+        )
+        params, opt_state, stats = adamw_update(opt_cfg, params, grads, opt_state, lr)
+        return params, opt_state, {"lr": lr, **stats}
+
     def train_fn(params, opt_state, batch):
         stack, first, last = MP.split_params(cfg, params)
         packed, mask = stage_plan.pack(stack)
@@ -402,16 +428,9 @@ def make_transformer_pipeline_train_step(
         grads = MP.merge_grads(
             cfg, stage_plan.unpack(packed_grads), first_grads, last_grads
         )
-        lr = warmup_cosine(
-            opt_state["step"], peak_lr=peak_lr, warmup_steps=warmup_steps,
-            total_steps=total_steps,
-        )
-        params, opt_state, stats = adamw_update(opt_cfg, params, grads, opt_state, lr)
-        metrics = {"loss": loss, "lr": lr}
-        metrics.update(stats)
-        return params, opt_state, metrics
+        params, opt_state, stats = apply_update(params, opt_state, grads)
+        return params, opt_state, {"loss": loss, **stats}
 
-    replicated = NamedSharding(mesh, P())
     b_abs = {
         "tokens": jax.ShapeDtypeStruct((global_batch, seq_len), jnp.int32),
         "targets": jax.ShapeDtypeStruct((global_batch, seq_len), jnp.int32),
@@ -420,7 +439,7 @@ def make_transformer_pipeline_train_step(
     return PipelineBuiltStep(
         fn=train_fn,
         abstract_inputs=(b_abs,),
-        in_shardings=(None, None, b_shard),
+        in_shardings=(p_shard, o_shard, b_shard),
         out_shardings=None,
         abstract_state={"params": p_abs, "opt_state": o_abs},
         tokens_per_call=global_batch * seq_len,

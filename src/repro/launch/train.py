@@ -56,6 +56,7 @@ from ..models.config import ArchConfig, ShapeConfig
 from ..monitor import MetricsExporter, MonitorServer, StatusWriter
 from ..optim import AdamWConfig, init_opt_state
 from ..timing import TimingSession
+from .cache import configure_compile_cache
 from .steps import (
     make_pipeline_train_step,
     make_train_step,
@@ -120,6 +121,16 @@ def _flops_per_step(cfg: ArchConfig, tokens: int) -> float:
     return 6.0 * active * tokens
 
 
+def _bytes_by_device(tree) -> dict[int, int]:
+    """Bytes of ``tree``'s device arrays held on each device id (replicas
+    counted on every device that holds one; host leaves are not counted)."""
+    out: dict[int, int] = {}
+    for leaf in jax.tree.leaves(tree):
+        for shard in getattr(leaf, "addressable_shards", ()):
+            out[shard.device.id] = out.get(shard.device.id, 0) + shard.data.nbytes
+    return dict(sorted(out.items()))
+
+
 def run_training(
     settings: TrainSettings,
     cfg: ArchConfig | None = None,
@@ -170,7 +181,7 @@ def run_training(
 
     # --- thorn state shared across routines -------------------------------------
     manager = None
-    logger = TimerLogger(settings.log_path) if settings.log_path else None
+    logger = TimerLogger(settings.log_path, db=db) if settings.log_path else None
     status = StatusWriter(settings.status_path) if settings.status_path else None
     monitor = None
     if pipelined and not settings.pipeline_model:
@@ -555,6 +566,11 @@ def run_training(
         ),
         "straggler_reports": len(detector.reports),
         "straggler_rows": straggler_rows(detector),
+        # where the trained state lives: a mesh or pipeline that left it all
+        # on one device shows here as a single entry
+        "state_bytes_by_device": _bytes_by_device(
+            (st["params"], st["opt_state"])
+        ),
         "adapt": loop.summary(),
         "adapt_rows": adapt_rows(loop),
         # the hierarchical profile: nested inclusive/exclusive rows derived
@@ -616,6 +632,7 @@ def main(argv=None) -> int:
                          "period per stage slot, embed/head pinned to the end "
                          "stages) instead of the synthetic residual-MLP stack")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     settings = TrainSettings(
         arch=args.arch, smoke=args.smoke, steps=args.steps,

@@ -747,7 +747,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.dist.compat import shard_map
 from repro.dist.meshutil import local_mesh, remove_host
 
 mesh = local_mesh((8,), ("data",))
@@ -760,7 +759,7 @@ kept = [d.id for d in small.devices.flat]
 assert kept == [0, 1, 2, 4, 5, 6, 7], kept
 
 # the rebuilt mesh computes: a psum over the surviving axis
-f = shard_map(
+f = jax.shard_map(
     lambda x: jax.lax.psum(x, "data"),
     mesh=small, in_specs=P("data"), out_specs=P(),
 )

@@ -27,7 +27,7 @@ from jax.sharding import PartitionSpec as P
 assert jax.device_count() == 8
 
 from repro.configs import get_smoke_config
-from repro.dist.compat import cost_analysis
+from repro.dist.meshutil import local_mesh
 from repro.dist.sharding import DEFAULT_RULES
 from repro.launch.hlo import collective_bytes
 from repro.launch.steps import build_step, input_specs, rules_for
@@ -36,7 +36,7 @@ from repro.models.config import ShapeConfig
 out = {}
 
 # --- mini multi-pod dry-run: (pod, data, model) = (2, 2, 2) -----------------
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = local_mesh((2, 2, 2), ("pod", "data", "model"))
 for arch in ["llama3.2-1b", "moonshot-v1-16b-a3b", "recurrentgemma-9b", "rwkv6-1.6b"]:
     cfg = get_smoke_config(arch)
     for kind, shape in [
@@ -52,7 +52,7 @@ for arch in ["llama3.2-1b", "moonshot-v1-16b-a3b", "recurrentgemma-9b", "rwkv6-1
         coll = collective_bytes(compiled.as_text())
         out[f"{arch}:{kind}"] = {
             "collective_bytes": sum(coll.values()),
-            "flops": cost_analysis(compiled).get("flops", -1.0),
+            "flops": compiled.cost_analysis().get("flops", -1.0),
         }
 
 # --- pipeline parallelism over the pod axis ---------------------------------
@@ -67,13 +67,12 @@ x = jax.random.normal(jax.random.PRNGKey(1), (8, d), jnp.float32)
 def layer_fn(w, xm):
     return jnp.tanh(xm @ w)
 
-pp_mesh = jax.make_mesh((2, 4), ("pod", "data"))
+pp_mesh = local_mesh((2, 4), ("pod", "data"))
 y_pp = gpipe_forward(layer_fn, stage_w, x, mesh=pp_mesh, axis="pod", n_micro=4)
 y_ref = layer_fn(stage_w[1], layer_fn(stage_w[0], x))
 out["pipeline_max_err"] = float(jnp.max(jnp.abs(y_pp - y_ref)))
 
 # --- compressed cross-pod reduction inside shard_map -------------------------
-from repro.dist.compat import shard_map
 from repro.optim.compression import cross_pod_mean_compressed, ef_init
 
 g = jax.random.normal(jax.random.PRNGKey(2), (2, 64), jnp.float32)  # per-pod grads
@@ -83,9 +82,9 @@ def reducer(g_local, ef):
     return mean["g"], new_ef
 
 ef0 = ef_init({"g": g[0]})
-fn = shard_map(
+fn = jax.shard_map(
     reducer, mesh=pp_mesh, in_specs=(P("pod"), P()), out_specs=(P(), P()),
-    check=False,
+    check_vma=False,
 )
 mean, _ = fn(g, ef0)
 true_mean = jnp.mean(g, axis=0)

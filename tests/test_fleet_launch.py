@@ -94,7 +94,9 @@ def test_two_rank_join_and_kill_smoke(tmp_path):
 def test_zero_horizon_defers_join_with_measured_cost(tmp_path):
     settings = FleetSettings(
         hosts=2,
-        steps=12,
+        # the joiner needs ~0.5 s of interpreter start-up before it can ask
+        # to join; 16 polls of 0.1 s after the spawn leave it room on a busy host
+        steps=20,
         step_floor_s=0.02,
         poll_interval_s=0.1,
         liveness_timeout_s=2.0,

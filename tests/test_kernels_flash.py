@@ -62,15 +62,24 @@ def test_flash_gradients_match_ref(causal, window):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=5e-4, rtol=5e-4)
 
 
-def test_flash_falls_back_on_untiled_shapes():
-    """Non-multiple-of-block shapes route to the chunked pure-JAX path."""
+@pytest.mark.parametrize("s", [100, 384])
+def test_flash_falls_back_on_untiled_shapes(s):
+    """Shapes the default 128-blocks do not tile still run the kernel: a short
+    axis as one whole tile (100), a longer one with the largest 128-multiple
+    block that divides it (384 with block 256 -> 128)."""
     ks = jax.random.split(jax.random.PRNGKey(2), 3)
-    q = _rand(ks[0], (1, 100, 2, 64), jnp.float32)
-    k = _rand(ks[1], (1, 100, 2, 64), jnp.float32)
-    v = _rand(ks[2], (1, 100, 2, 64), jnp.float32)
-    out = flash_attention(q, k, v, causal=True, interpret=True)
+    q = _rand(ks[0], (1, s, 2, 64), jnp.float32)
+    k = _rand(ks[1], (1, s, 2, 64), jnp.float32)
+    v = _rand(ks[2], (1, s, 2, 64), jnp.float32)
+    out = flash_attention(q, k, v, causal=True, block_q=256, block_k=256, interpret=True)
     ref = attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
+
+
+def test_flash_refuses_shapes_with_no_legal_tiling():
+    q = jnp.zeros((1, 300, 2, 64), jnp.float32)
+    with pytest.raises(ValueError, match="cannot tile"):
+        flash_attention(q, q, q, causal=True, interpret=True)
 
 
 def test_flash_jit_compatible():

@@ -173,6 +173,56 @@ def test_train_launcher_pipeline_model_path():
 # Real multi-stage ring (forced 4-device topology, nightly tier)
 # ---------------------------------------------------------------------------
 
+LAUNCHER_MULTIDEVICE_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+from repro.configs import get_smoke_config
+from repro.core.params import reset_param_registry
+from repro.launch.train import TrainSettings, run_training
+from repro.timing import TimingSession
+
+cfg = get_smoke_config("llama3.2-1b").replace(n_layers=4)
+
+def run(**kw):
+    reset_param_registry()
+    settings = TrainSettings(steps=2, global_batch=4, seq_len=32, ckpt_mode="off",
+                             report_every=0, **kw)
+    return run_training(settings, cfg=cfg, session=TimingSession())
+
+one = run()
+pipe = run(pipeline_stages=4, pipeline_model=True, pipeline_micro=2)
+loss, ref = pipe["final_metrics"]["loss"], one["final_metrics"]["loss"]
+assert abs(loss - ref) <= 1e-4 * abs(ref), (loss, ref)
+held = pipe["state_bytes_by_device"]
+assert sorted(held) == [0, 1, 2, 3], held
+# the stack is split over the stages: no device holds the one-device state
+assert max(held.values()) < sum(one["state_bytes_by_device"].values()), held
+print("PIPELINE_LAUNCHER_MULTIDEVICE_OK")
+"""
+
+
+@pytest.mark.multihost
+@pytest.mark.slow
+def test_pipeline_launcher_spreads_state_over_stage_devices():
+    """run_training's 1F1B path on 4 devices matches the one-device run and
+    keeps each stage's share of the stack (and its optimizer state) on that
+    stage's device instead of every device holding all of it."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["PYTHONPATH"] = (
+        os.path.join(repo, "src") + os.pathsep + env.get("PYTHONPATH", "")
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", LAUNCHER_MULTIDEVICE_SCRIPT],
+        capture_output=True, text=True, timeout=900, env=env, cwd=repo,
+    )
+    assert proc.returncode == 0, proc.stderr
+    assert "PIPELINE_LAUNCHER_MULTIDEVICE_OK" in proc.stdout
+
 MULTIDEVICE_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"

@@ -35,6 +35,9 @@ def test_training_loop_runs_and_profiles(tmp_path):
     bins = summary["bin_seconds"]
     assert bins["EVOL"] > 0 and bins["STARTUP"] > 0
     assert summary["checkpoint"]["n_checkpoints"] >= 1
+    # one device: the whole trained state is reported on device 0
+    held = summary["state_bytes_by_device"]
+    assert list(held) == [jax.devices()[0].id] and held[jax.devices()[0].id] > 0
     # the hierarchical profile: >=3-deep scope nesting with consistent
     # inclusive/exclusive seconds (simulation/total -> bin -> routine -> scope)
     def depth(row):
