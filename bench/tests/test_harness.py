@@ -1,0 +1,167 @@
+"""The harness finds everything by name from data, and speaks its contract."""
+
+import json
+import math
+import os
+import shutil
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+from bench import harness
+from bench.traffic import serve_arrivals
+from conftest import ROOT
+
+BENCHMARK = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+
+
+@pytest.mark.parametrize("name", [w["name"] for w in BENCHMARK["workloads"]])
+def test_every_cell_resolves_from_data(name):
+    cell = harness.load_cell(name)
+    assert cell.config["name"] == cell.config_name
+    assert harness.driver_module(cell).run
+    assert harness.reference_module(cell).served_token_gaps
+    assert cell.end_to_end and cell.per_layer
+    assert "setup_s" in [m["name"] for m in cell.end_to_end]
+    for m in cell.per_layer:
+        assert callable(harness.metric_reader(m["name"]).read)
+
+
+def test_every_metric_has_a_reader_and_moves_a_reported_metric():
+    e2e = {m["name"]: m for m in BENCHMARK["end_to_end"]}
+    for m in BENCHMARK["per_layer"]:
+        assert os.path.exists(os.path.join(ROOT, "bench", "metrics", m["name"] + ".py"))
+        moved = e2e[m["moves"]]
+        for cell in m["workloads"]:
+            assert "workloads" not in moved or cell in moved["workloads"]
+
+
+def test_a_cell_added_as_files_alone_is_found(tmp_path):
+    """A later PR adds a configuration, a traffic mix, limits and a metric as
+    new files plus entries in BENCHMARK.json, and edits no existing file."""
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path / "BENCHMARK.json")
+    shutil.copytree(os.path.join(ROOT, "bench"), tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__", "testdata"))
+    b = json.load(open(tmp_path / "BENCHMARK.json"))
+    conf = json.load(open(tmp_path / "bench/configs/glm4-9b-serve.json"))
+    conf["name"] = "fixture-model"
+    json.dump(conf, open(tmp_path / "bench/configs/fixture-model.json", "w"))
+    traffic = json.load(open(tmp_path / "bench/traffic/chat.json"))
+    traffic["rate_rps"] = 1.0
+    json.dump(traffic, open(tmp_path / "bench/traffic/fixture-mix.json", "w"))
+    json.dump({"token_gap": 1.0}, open(tmp_path / "bench/limits/fixture.cell.json", "w"))
+    (tmp_path / "bench/metrics/fixture_metric.py").write_text(
+        "def read(outcome):\n    return 42.0\n")
+    b["configs"].append({"name": "fixture-model", "source": "https://example.org/fixture",
+                         "file": "bench/configs/fixture-model.json", "reduced": [], "why": "x"})
+    b["workloads"].append({"name": "fixture.cell", "config": "fixture-model",
+                           "traffic": "fixture-mix", "chips": 1, "why": "x"})
+    b["per_layer"].append({"name": "fixture_metric", "unit": "%", "better": "higher",
+                           "source": "program_span", "layer": "serving engine",
+                           "moves": "ttft_p95_ms", "workloads": ["fixture.cell"]})
+    b["end_to_end"] += [{"name": name, "unit": "ms", "better": "lower", "bound": 0.2,
+                         "source": "host_clock", "workloads": ["fixture.cell"]}
+                        for name in ("ttft_p95_ms", "tpot_p95_ms")]
+    json.dump(b, open(tmp_path / "BENCHMARK.json", "w"))
+
+    cell = harness.load_cell("fixture.cell", root=tmp_path)
+    assert cell.config["name"] == "fixture-model"
+    assert cell.traffic["rate_rps"] == 1.0
+    assert cell.limits == {"token_gap": 1.0}
+    assert [m["name"] for m in cell.per_layer] == ["fixture_metric"]
+    assert {m["name"] for m in cell.end_to_end} == {"ttft_p95_ms", "tpot_p95_ms", "setup_s"}
+    assert harness.driver_module(cell, root=tmp_path).run
+    assert harness.metric_reader("fixture_metric", root=tmp_path).read(None) == 42.0
+
+
+def test_serve_traffic_is_a_function_of_the_seed():
+    spec = json.load(open(os.path.join(ROOT, "bench/traffic/chat.json")))
+    segments = [5.0, 40.0, 10.0]
+    a = serve_arrivals(spec, 2**33 + 7, segments, 151552)
+    b = serve_arrivals(spec, 2**33 + 7, segments, 151552)
+    c = serve_arrivals(spec, 2**33 + 8, segments, 151552)
+    assert [(x.at, x.prompt, x.max_new) for x in a] == [(x.at, x.prompt, x.max_new) for x in b]
+    assert [x.prompt for x in a] != [x.prompt for x in c]
+    assert len(a) == sum(math.ceil(s * spec["rate_rps"]) for s in segments)
+    # another seed offers the window the same work in another order
+    window = lambda xs: [x for x in xs if 5.0 <= x.at < 45.0]  # noqa: E731
+    wa, wc = window(a), window(c)
+    assert len(wa) == len(wc) == math.ceil(40.0 * spec["rate_rps"])
+    assert [x.at for x in wa] != [x.at for x in wc]
+    assert sorted(len(x.prompt) for x in wa) == sorted(len(x.prompt) for x in wc)
+    assert sorted(x.max_new for x in wa) == sorted(x.max_new for x in wc)
+    gaps = lambda xs: np.diff([x.at for x in xs] + [45.0])  # noqa: E731
+    np.testing.assert_allclose(sorted(gaps(wa)), sorted(gaps(wc)), rtol=1e-9)
+    assert {len(x.prompt) for x in a} <= {256, 512, 1024, 2048}
+    assert all(32 <= x.max_new <= 512 for x in a)
+
+
+def test_train_traffic_is_a_function_of_the_seed():
+    from bench.drivers.train import tokens_at
+
+    a, _ = tokens_at(2**33 + 7, 0, 1, 4096, 18944)
+    b, _ = tokens_at(2**33 + 7, 0, 1, 4096, 18944)
+    c, _ = tokens_at(2**33 + 8, 0, 1, 4096, 18944)
+    d, _ = tokens_at(2**33 + 7, 1, 1, 4096, 18944)
+    assert (a == b).all() and not (a == c).all() and not (a == d).all()
+
+
+def test_jax_seed_takes_large_seeds():
+    seeds = {harness.jax_seed(s) for s in (0, 1, 2**31 + 5, 2**40 + 1)}
+    assert len(seeds) == 4 and all(0 <= s < 2**31 for s in seeds)
+
+
+@pytest.mark.parametrize("which", ["train", "serve"])
+def test_result_line_has_the_contract_keys(which, tiny_train, tiny_serve, v5e, capsys):
+    cell = tiny_train if which == "train" else tiny_serve
+    result = harness.run_cell(cell.name, 2**31 + 11, 1.0, False, require_chip=False,
+                              cell=cell, peak=v5e)
+    assert list(result) == ["correct", "attempted", "failed", "metrics", "device", "checks"]
+    assert set(result["metrics"]) == {m["name"] for m in cell.end_to_end}
+    assert set(result["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
+    assert result["correct"] is True and result["attempted"] > 0
+    harness.emit(result)
+    out, err = capsys.readouterr()
+    assert json.loads(out.strip().splitlines()[-1]) == json.loads(json.dumps(result))
+    assert err.strip().splitlines()[-1].startswith("check ")
+
+
+@pytest.mark.parametrize("which", ["train", "serve"])
+def test_reference_runs_once_the_program_is_gone(which, tiny_train, tiny_serve, v5e,
+                                                 monkeypatch):
+    """At the cells' size the program's state and the reference do not fit on
+    one chip together: no array the run made may be alive when it starts."""
+    import gc
+
+    import jax
+
+    from bench.reference import dense_gqa as R
+
+    cell = tiny_train if which == "train" else tiny_serve
+    name = "train_reference" if which == "train" else "served_token_gaps"
+    before = {id(x) for x in jax.live_arrays()}
+    alive = []
+
+    def spy(*args, **kwargs):
+        gc.collect()
+        alive.append([x.shape for x in jax.live_arrays() if id(x) not in before])
+        return getattr(R, "_" + name)(*args, **kwargs)
+
+    monkeypatch.setattr(R, "_" + name, getattr(R, name), raising=False)
+    monkeypatch.setattr(R, name, spy)
+    harness.run_cell(cell.name, 2**31 + 13, 1.0, False, require_chip=False, cell=cell, peak=v5e)
+    assert alive == [[]]
+
+
+def test_command_exits_nonzero_without_a_chip():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, "bench/run.py", "--workload", "train.glm4-9b.4k", "--seed", "1",
+         "--seconds", "1", "--trace", "0"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert p.returncode != 0
+    assert "{" not in p.stdout
+    assert "no TPU" in p.stderr
