@@ -5,7 +5,9 @@ named bins.  The scheduler controls execution order and is "a natural place to
 put caliper points": every scheduled routine is wrapped in a timer named
 ``<BIN>/<thorn>::<routine>`` automatically, so any user or routine can obtain
 timing statistics for any routine by querying the timer database — no explicit
-instrumentation required.
+instrumentation required.  While a JAX profiler trace records, every timer
+window is also a span in it (:mod:`repro.core.timers`) and each loop iteration
+is a profiler step whose ``step_num`` is ``RunState.iteration``.
 
 Bins mirror the lifecycle of a training/serving run:
 
@@ -29,7 +31,7 @@ from collections.abc import Callable, Sequence
 from dataclasses import dataclass, field
 from typing import Any
 
-from .timers import TimerDB, timer_db
+from .timers import TimerDB, step_marker, timer_db
 
 
 __all__ = ["BINS", "RunState", "ScheduledRoutine", "Scheduler", "schedule_bin_timer_name"]
@@ -230,10 +232,13 @@ class Scheduler:
             self.run_bin("STARTUP", state)
             self.run_bin("INITIAL", state)
             while not state.should_terminate and state.iteration < state.max_iterations:
-                for bin in _LOOP_BINS:
-                    self.run_bin(bin, state)
-                    if state.should_terminate:
-                        break
+                # a profiler step marker per iteration: while a trace records,
+                # the spans of one iteration share its step number
+                with step_marker(state.iteration):
+                    for bin in _LOOP_BINS:
+                        self.run_bin(bin, state)
+                        if state.should_terminate:
+                            break
                 state.iteration += 1
             self.run_bin("SHUTDOWN", state)
         return state

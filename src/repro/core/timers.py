@@ -33,6 +33,15 @@ Flattened views namespace colliding channel names as ``<clock>.<channel>``
 (two clocks exporting the same channel no longer silently overwrite each
 other).
 
+Every window is also a host span in the JAX profiler's trace while one is
+being recorded (``jax.profiler.start_trace`` / ``trace``): the span is named by
+the timer path and carries one metadata stat, ``parent``, the enclosing timer
+path (``"/"`` at the top: the profiler drops empty values).  The profiler
+records the spans on the timeline of its device lines, so device idle time
+can be laid against the timer tree.  With no trace recording a window pays
+one ``TraceMe.is_enabled()`` check and creates no span; ``jaxlib`` is
+resolved on the first window, not on import.
+
 The supported call-path-facing surface lives one layer up in
 :mod:`repro.timing`: hierarchical scopes (:meth:`TimerDB.scope` /
 :meth:`TimerDB.scope_handle`) derive path-addressed timers from the running
@@ -46,7 +55,7 @@ from __future__ import annotations
 
 import threading
 from collections.abc import Iterator, Mapping
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 
 from . import clocks as _clocks
@@ -60,6 +69,7 @@ __all__ = [
     "TimerNode",
     "path_matches",
     "reset_timer_db",
+    "step_marker",
     "timer_db",
 ]
 
@@ -91,6 +101,45 @@ def path_matches(name: str, prefix: str) -> bool:
 
 class TimerError(RuntimeError):
     pass
+
+
+# -- profiler spans --------------------------------------------------------------
+#: ``parent`` stat of a top-level window (the profiler drops empty values)
+ROOT_PARENT = "/"
+#: name of the profiler step marker around each scheduler loop iteration
+STEP_MARKER = "iteration"
+_NO_SPAN = nullcontext()
+_TraceMe = None  # jaxlib's TraceMe (jax.profiler.TraceAnnotation's base class)
+
+
+def _never() -> bool:
+    return False
+
+
+def _resolve_trace_enabled() -> bool:
+    """Bind the profiler sink on the first window: ``_trace_enabled`` becomes
+    ``TraceMe.is_enabled`` (``_never`` where jaxlib is not installed)."""
+    global _TraceMe, _trace_enabled
+    try:
+        from jaxlib._profiler import TraceMe
+    except ImportError:
+        _trace_enabled = _never
+    else:
+        _TraceMe, _trace_enabled = TraceMe, TraceMe.is_enabled
+    return _trace_enabled()
+
+
+#: True while a profiler trace is recording: the one check a window pays
+_trace_enabled = _resolve_trace_enabled
+
+
+def step_marker(step: int):
+    """A context manager that marks one loop iteration as profiler step
+    ``step`` (what ``jax.profiler.StepTraceAnnotation`` records), so every
+    span inside it shares the step number; a no-op while no trace records."""
+    if not _trace_enabled():
+        return _NO_SPAN
+    return _TraceMe(STEP_MARKER, _r=1, step_num=step)
 
 
 class _FusedClockView:
@@ -242,6 +291,7 @@ class Timer:
         "_parent_path",
         "_parent_stats",
         "parent_stats_evictions",
+        "_span",
     )
 
     def __init__(self, name: str, handle: int) -> None:
@@ -265,6 +315,8 @@ class Timer:
         self._parent_path: tuple[str, ...] = ()
         self._parent_stats: dict[tuple[str, ...], list] = {}
         self.parent_stats_evictions = 0
+        # the open window's profiler span (None while no trace records)
+        self._span = None
 
     # -- layout management (lock held) ----------------------------------------
     def _sync_layout_locked(self) -> None:
@@ -294,7 +346,11 @@ class Timer:
         self._views = None
 
     # -- lifecycle -----------------------------------------------------------
-    def start(self) -> None:
+    def start(self, parent_path: tuple[str, ...] | None = None) -> None:
+        """Open a window attributed to ``parent_path``, the chain of enclosing
+        scope paths (``None`` keeps the last window's attribution).  While a
+        profiler trace records, the window is also a span named by the timer
+        path with the enclosing path as its ``parent`` stat."""
         with self._lock:
             if self.running:
                 raise TimerError(f"timer {self.name!r} already running")
@@ -317,6 +373,12 @@ class Timer:
                     for clock in started:
                         clock.stop()
                     raise
+            if parent_path is not None:
+                self._parent_path = parent_path
+                self.parent_name = parent_path[-1] if parent_path else None
+            if _trace_enabled():
+                self._span = _TraceMe(self.name, parent=self.parent_name or ROOT_PARENT)
+                self._span.__enter__()
             self._marks = marks
             self.running = True
 
@@ -324,6 +386,12 @@ class Timer:
         with self._lock:
             if not self.running:
                 raise TimerError(f"timer {self.name!r} is not running")
+            # each window owns its span, so out-of-order stops close the
+            # right one; a window opened before the trace started has none
+            span = self._span
+            if span is not None:
+                self._span = None
+                span.__exit__(None, None, None)
             # stop non-fused clocks first: their on_stop hooks can raise, and
             # a retried stop() must not re-apply the fused diff (Clock.stop
             # no-ops when already stopped, so the retry is safe either way)
@@ -542,11 +610,9 @@ class ScopeHandle:
             stack = tls.stack
         except AttributeError:
             stack = tls.stack = []
-        # start first: a failed start (double enter) must not corrupt the
-        # open window's recorded attribution
-        timer.start()
-        timer.parent_name = stack[-1] if stack else None
-        timer._parent_path = tuple(stack)
+        # a failed start (double enter) raises before it touches the open
+        # window's recorded attribution
+        timer.start(tuple(stack))
         stack.append(timer.name)
         return timer
 
@@ -671,9 +737,7 @@ class TimerDB:
             stack = self._tls.stack
         except AttributeError:
             stack = self._tls.stack = []
-        timer.start()  # before attribution: a double start must not corrupt it
-        timer.parent_name = stack[-1] if stack else None
-        timer._parent_path = tuple(stack)
+        timer.start(tuple(stack))
         stack.append(timer.name)
 
     def stop(self, ref: int | str) -> None:
@@ -772,9 +836,7 @@ class TimerDB:
         if handle is None:
             handle = self.create(path)
         timer = self._timers[handle]
-        timer.start()  # before attribution: a double start must not corrupt it
-        timer.parent_name = stack[-1] if stack else None
-        timer._parent_path = tuple(stack)
+        timer.start(tuple(stack))
         stack.append(path)
         try:
             yield timer

@@ -116,9 +116,21 @@ class TrainSettings:
     pipeline_model: bool = False
 
 
-def _flops_per_step(cfg: ArchConfig, tokens: int) -> float:
+def _flops_per_step(cfg: ArchConfig, seq_len: int, n_seqs: int) -> float:
+    """Model FLOPs of one training step: 6 x (active layer params + head
+    params) x tokens, plus forward and backward (3 x 4 x pairs x query width)
+    of causal attention over the (query, key) pairs each attention layer
+    scores; no recomputation is counted."""
     _, active = M.param_counts(cfg)
-    return 6.0 * active * tokens
+    dense = 6.0 * (active + cfg.d_model * cfg.vocab_size) * seq_len * n_seqs
+    pairs = 0
+    for kind in cfg.layer_kinds():
+        if kind == "attn":
+            pairs += seq_len * (seq_len + 1) // 2
+        elif kind == "attn_local":
+            w = min(cfg.window or seq_len, seq_len)
+            pairs += w * (w + 1) // 2 + (seq_len - w) * w
+    return dense + 12.0 * n_seqs * pairs * cfg.q_dim
 
 
 def _bytes_by_device(tree) -> dict[int, int]:
@@ -191,11 +203,17 @@ def run_training(
         active = settings.pipeline_layers * 2 * settings.pipeline_width ** 2
         model_flops = 6.0 * active * settings.global_batch * settings.seq_len
     else:
-        model_flops = _flops_per_step(cfg, settings.global_batch * settings.seq_len)
+        model_flops = _flops_per_step(cfg, settings.seq_len, settings.global_batch)
 
     # --- the control plane: one loop, every adaptation registered on it ----------
     ckpt_timer_name = "CHECKPOINT/adaptcheck::write"
     ckpt_write_scope = sess.scope_handle(ckpt_timer_name)
+    # the train step's window in three parts: the host enqueuing the compiled
+    # step, the wait for the device to finish it, and the metrics' copy to
+    # the host
+    dispatch_scope = sess.scope_handle("EVOL/trainer::train_step/dispatch")
+    wait_scope = sess.scope_handle("EVOL/trainer::train_step/wait")
+    fetch_scope = sess.scope_handle("EVOL/trainer::train_step/fetch")
     loop = sess.control_loop
     policy = AdaptiveCheckpointPolicy(
         mode="adaptive" if settings.ckpt_mode == "adaptive" else "fixed",
@@ -469,11 +487,14 @@ def run_training(
 
     # --- EVOL: the jitted step -----------------------------------------------------
     def train_step(s: RunState) -> None:
-        params, opt_state, metrics = s["exec"](s["params"], s["opt_state"], s["batch"])
-        metrics = jax.block_until_ready(metrics)
+        with dispatch_scope:
+            params, opt_state, metrics = s["exec"](s["params"], s["opt_state"], s["batch"])
+        with wait_scope:
+            metrics = jax.block_until_ready(metrics)
         s["params"], s["opt_state"] = params, opt_state
         s["updates"] = s.iteration + 1
-        s["metrics"] = {k: float(v) for k, v in metrics.items()}
+        with fetch_scope:
+            s["metrics"] = {k: float(v) for k, v in metrics.items()}
         bump_flops(model_flops)
         bump_tokens(float(s["built"].tokens_per_call))
         bump_steps(1.0)
