@@ -20,7 +20,7 @@ from jax.sharding import Mesh, NamedSharding
 from .sharding import ShardingRules, spec_for
 
 
-__all__ = ["use_sharding", "current_sharding", "constrain"]
+__all__ = ["use_sharding", "current_sharding", "current_platform", "constrain"]
 
 _STATE = threading.local()
 
@@ -39,6 +39,16 @@ def use_sharding(mesh: Mesh, rules: ShardingRules) -> Iterator[None]:
 def current_sharding() -> tuple[Mesh, ShardingRules] | None:
     """The active (mesh, rules) pair, or ``None`` outside ``use_sharding``."""
     return getattr(_STATE, "context", None)
+
+
+def current_platform() -> str:
+    """The platform the code being traced runs on: that of the ambient mesh's
+    devices, or the default backend's outside a context or for an abstract
+    mesh.  A compile for a described TPU from a CPU process reads ``"tpu"``."""
+    context = current_sharding()
+    if context is not None and isinstance(context[0], Mesh):
+        return context[0].devices.flat[0].platform
+    return jax.default_backend()
 
 
 def constrain(x: jax.Array, *logical_axes: str | None) -> jax.Array:

@@ -52,6 +52,7 @@ from ..dist.pipeline import MicrobatchPlan, StagePlan, phase_ticks
 from ..dist.stragglers import StragglerDetector
 from ..fleet.topology import stage_for_host
 from ..models import model as M, pipeline as model_pipeline
+from ..models.attention import select_impl
 from ..models.config import ArchConfig, ShapeConfig
 from ..monitor import MetricsExporter, MonitorServer, StatusWriter
 from ..optim import AdamWConfig, init_opt_state
@@ -131,6 +132,15 @@ def _flops_per_step(cfg: ArchConfig, seq_len: int, n_seqs: int) -> float:
             w = min(cfg.window or seq_len, seq_len)
             pairs += w * (w + 1) // 2 + (seq_len - w) * w
     return dense + 12.0 * n_seqs * pairs * cfg.q_dim
+
+
+def attention_layers_by_path(cfg: ArchConfig, seq_len: int, *, devices: int) -> tuple[int, int]:
+    """(flash, chunked): how many of the train step's attention layers
+    ``select_impl`` sends to the flash kernel and to the chunked scan, for
+    arrays spread over ``devices`` devices."""
+    impl = select_impl(cfg.attn_impl, seq_len, seq_len, devices=devices)
+    n = M.attention_layers(cfg)
+    return (n if impl == "pallas" else 0, n if impl == "chunked" else 0)
 
 
 def _bytes_by_device(tree) -> dict[int, int]:
@@ -322,10 +332,21 @@ def run_training(
     bump_flops = sess.counter("xla_flops", absolute=True)
     bump_tokens = sess.counter("tokens", absolute=True)
     bump_steps = sess.counter("steps", absolute=True)
+    # which attention the step runs, counted once at startup (the MLP
+    # pipeline has none; the pipeline's stages see one device's arrays)
+    attn_flash, attn_chunked = (
+        attention_layers_by_path(cfg, settings.seq_len, devices=1 if pipelined else mesh.size)
+        if not pipelined or settings.pipeline_model
+        else (0, 0)
+    )
+    bump_attn_flash = sess.counter("attn_flash_layers")
+    bump_attn_chunked = sess.counter("attn_chunked_layers")
 
     # --- STARTUP ----------------------------------------------------------------
     def startup(s: RunState) -> None:
         nonlocal manager, monitor
+        bump_attn_flash(attn_flash)
+        bump_attn_chunked(attn_chunked)
         opt_cfg = AdamWConfig()
         horizon = settings.lr_total_steps or settings.steps
         if pipelined:
@@ -679,7 +700,8 @@ def main(argv=None) -> int:
         # in the DB; the session's control loop supplies the decision log and
         # the tree report adds the hierarchical self-vs-children view
         print(format_report(
-            sess.db, channels=("walltime", "cputime", "xla_flops"),
+            sess.db, channels=("walltime", "cputime", "xla_flops",
+                               "attn_flash_layers", "attn_chunked_layers"),
             adapt=sess.control_loop,
         ))
         print()

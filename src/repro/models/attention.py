@@ -1,14 +1,18 @@
-"""Attention: GQA with causal / sliding-window masks, three implementations.
+"""Attention: GQA with causal / sliding-window masks, three implementations
+and the default that chooses between two of them.
 
 * ``naive``   — full (S×T) score matrix; the oracle (also ``kernels/flash_attention/ref.py``).
-* ``chunked`` — memory-efficient exact attention: ``lax.scan`` over query
-  chunks; each chunk computes an exact softmax against (a band of) K/V, so the
-  S×T buffer never materializes.  This is the default everywhere and is what
-  the dry-run lowers — the memory-roofline win is visible in ``cost_analysis``.
-  For sliding-window attention only the K/V band covering the window is sliced
-  per chunk (compute O(S·window) instead of O(S²)).
-* ``pallas``  — the TPU flash-attention kernel (kernels/flash_attention);
-  selected on TPU backends, falls back to ``chunked`` elsewhere.
+* ``chunked`` — exact attention, ``lax.scan`` over query chunks; each chunk
+  computes an exact softmax against (a band of) K/V.  Under autodiff the scan
+  keeps every chunk's (chunk, T) scores as residuals.  For sliding-window
+  attention only the K/V band covering the window is sliced per chunk
+  (compute O(S·window) instead of O(S²)).
+* ``pallas``  — the flash-attention kernel (kernels/flash_attention): online
+  softmax, causal blocks skipped, a Pallas backward; nothing of size S×T
+  leaves the chip's fast memory.  Raises on shapes it cannot tile.
+* ``auto``    — the default: ``pallas`` where :func:`select_impl` finds a TPU,
+  S and T that tile by 128 and one device's arrays, ``chunked`` elsewhere (so
+  every CPU run, tests and the dry-run included, takes ``chunked``).
 
 Shapes: q (B, S, H, hd); k/v (B, T, KV, hd); GQA group = H // KV.
 """
@@ -18,10 +22,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..dist.context import current_platform, current_sharding
 
-__all__ = ["attention", "decode_attention"]
+
+__all__ = ["attention", "decode_attention", "select_impl"]
 
 _NEG_INF = -2.0e38
+#: the flash kernel's tiles are multiples of the TPU's 128 lanes
+_FLASH_TILE = 128
 
 
 def _split_heads(q: jax.Array, n_kv: int) -> jax.Array:
@@ -120,17 +128,44 @@ def chunked_attention(
     return _merge_heads(outs)
 
 
+def select_impl(
+    impl: str, s: int, t: int, *, q_offset: int = 0, devices: int | None = None
+) -> str:
+    """The implementation ``attention`` runs for ``impl`` over S queries and
+    T keys: ``impl`` itself unless it is ``"auto"``.  ``"auto"`` runs the
+    flash kernel when the platform (:func:`current_platform`) is a TPU, S and
+    T tile by 128, the queries start at position 0, and the arrays are one
+    device's: ``devices`` (by default the ambient sharding mesh's size, 1
+    outside one, as in the pipeline's per-device stages) is 1.  A
+    multi-device jit stays chunked: XLA would gather the kernel's inputs onto
+    every device."""
+    if impl != "auto":
+        return impl
+    if devices is None:
+        context = current_sharding()
+        devices = 1 if context is None else context[0].size
+    flash = (
+        current_platform() == "tpu"
+        and s % _FLASH_TILE == 0
+        and t % _FLASH_TILE == 0
+        and q_offset == 0
+        and devices == 1
+    )
+    return "pallas" if flash else "chunked"
+
+
 def attention(
     q: jax.Array,
     k: jax.Array,
     v: jax.Array,
     *,
-    impl: str = "chunked",
+    impl: str = "auto",
     causal: bool = True,
     window: int | None = None,
     chunk: int = 512,
     q_offset: int = 0,
 ) -> jax.Array:
+    impl = select_impl(impl, q.shape[1], k.shape[1], q_offset=q_offset)
     if impl == "naive":
         return naive_attention(q, k, v, causal=causal, window=window, q_offset=q_offset)
     if impl == "chunked":

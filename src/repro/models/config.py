@@ -63,8 +63,10 @@ class ArchConfig:
     sharding: str = "tp"
     #: remat policy for the layer scan: "none" | "dots" | "full"
     remat: str = "dots"
-    #: attention implementation: "naive" | "chunked" (default) | "pallas"
-    attn_impl: str = "chunked"
+    #: attention implementation: "auto" (default: the flash kernel on a TPU for
+    #: S and T that tile by 128 over one device's arrays, else "chunked"; see
+    #: models/attention.select_impl) | "naive" | "chunked" | "pallas"
+    attn_impl: str = "auto"
     attn_chunk: int = 512
     #: rmsnorm implementation: "ref" (unfused, default) | "fused" (Pallas
     #: kernel; interpret-mode on CPU via default_interpret)

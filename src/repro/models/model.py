@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from ..dist.context import constrain
+from ..kernels.flash_attention import ops as fa_ops
 from .blocks import block_apply, block_cache_specs, block_specs
 from .config import ArchConfig
 from .layers import PSpec, abstract, axes_tree, is_pspec, materialize, rms_norm, rotary_embedding
@@ -36,6 +37,7 @@ __all__ = [
     "abstract_params",
     "param_axes",
     "param_counts",
+    "attention_layers",
     "cache_specs",
     "cache_axes",
     "init_cache",
@@ -122,6 +124,16 @@ def param_counts(cfg: ArchConfig) -> tuple[int, int]:
     return total, active
 
 
+def attention_layers(cfg: ArchConfig) -> int:
+    """Full-sequence attentions in one forward of the train step: one per
+    ``attn``/``attn_local`` block, two per ``xattn`` block (self and cross),
+    one per encoder layer.  All of them attend over the step's sequence."""
+    pattern = decoder_pattern(cfg)
+    per_kind = {"attn": 1, "attn_local": 1, "xattn": 2}
+    decoder = sum(per_kind.get(pattern[i % len(pattern)], 0) for i in range(cfg.n_layers))
+    return decoder + (cfg.n_enc_layers if cfg.family == "encdec" else 0)
+
+
 # ---------------------------------------------------------------------------
 # Cache specs
 # ---------------------------------------------------------------------------
@@ -173,7 +185,13 @@ def _remat_wrap(cfg, fn):
     if cfg.remat == "none":
         return fn
     if cfg.remat == "dots":
-        policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        # matmul outputs without batch dims, and the flash kernel's output and
+        # logsumexp, so the backward does not run the kernel's forward again
+        policies = jax.checkpoint_policies
+        policy = policies.save_from_both_policies(
+            policies.dots_with_no_batch_dims_saveable,
+            policies.save_only_these_names(*fa_ops.RESIDUAL_NAMES),
+        )
         return jax.checkpoint(fn, policy=policy)
     return jax.checkpoint(fn)  # "full": save nothing
 

@@ -62,6 +62,64 @@ def test_flash_gradients_match_ref(causal, window):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=5e-4, rtol=5e-4)
 
 
+#: seq 512 cut into tiles of 128 and 256, so the masks skip some tiles, keep
+#: others whole and cut across the rest, and the index maps clamp
+TILED = [
+    # (S, T, H, KV, hd, causal, window, block_q, block_k)
+    (512, 512, 4, 2, 64, True, None, 128, 128),     # causal
+    (512, 512, 4, 2, 64, True, 128, 128, 128),      # window of one tile
+    (512, 512, 4, 1, 64, True, 200, 128, 256),      # window off the tile grid
+    (512, 512, 8, 2, 64, True, 64, 256, 128),       # window inside one q tile
+    (512, 512, 4, 2, 64, False, None, 128, 256),    # no mask
+    (256, 512, 4, 2, 64, False, None, 128, 128),    # cross-shape
+]
+
+
+def _tiled_inputs(s, t, h, kv, hd, dtype):
+    ks = jax.random.split(jax.random.PRNGKey(4), 4)
+    return (_rand(ks[0], (1, s, h, hd), dtype), _rand(ks[1], (1, t, kv, hd), dtype),
+            _rand(ks[2], (1, t, kv, hd), dtype), _rand(ks[3], (1, s, h, hd), dtype))
+
+
+def _max_rel_err(got, want):
+    """The largest error over the largest reference entry."""
+    want = np.asarray(want, np.float32)
+    return float(np.max(np.abs(np.asarray(got, np.float32) - want)) / np.max(np.abs(want)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("s,t,h,kv,hd,causal,window,bq,bk", TILED)
+def test_flash_forward_across_tiles_matches_ref(s, t, h, kv, hd, causal, window, bq, bk, dtype):
+    q, k, v, _ = _tiled_inputs(s, t, h, kv, hd, dtype)
+    out = flash_attention(q, k, v, causal=causal, window=window, block_q=bq, block_k=bk,
+                          interpret=True)
+    ref = attention_ref(q, k, v, causal=causal, window=window)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref, np.float32), **_tol(dtype)
+    )
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("s,t,h,kv,hd,causal,window,bq,bk", TILED)
+def test_flash_gradients_across_tiles_match_ref(s, t, h, kv, hd, causal, window, bq, bk, dtype):
+    """dq, dk and dv against the float32 oracle on the same inputs: within
+    5e-4 in float32, within 2e-2 of the largest entry with bf16 operands."""
+    q, k, v, do = _tiled_inputs(s, t, h, kv, hd, dtype)
+    _, vjp = jax.vjp(
+        lambda q, k, v: flash_attention(q, k, v, causal=causal, window=window, block_q=bq,
+                                        block_k=bk, interpret=True), q, k, v)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    with jax.default_matmul_precision("highest"):
+        _, ref_vjp = jax.vjp(
+            lambda q, k, v: attention_ref(q, k, v, causal=causal, window=window), *f32)
+        want = ref_vjp(do.astype(jnp.float32))
+    for got, ref in zip(vjp(do), want):
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=5e-4, rtol=5e-4)
+        else:
+            assert _max_rel_err(got, ref) <= 2e-2
+
+
 @pytest.mark.parametrize("s", [100, 384])
 def test_flash_falls_back_on_untiled_shapes(s):
     """Shapes the default 128-blocks do not tile still run the kernel: a short

@@ -22,7 +22,7 @@ import pytest
 from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.kernels.flash_attention.ops import flash_attention
+from repro.kernels.flash_attention.ops import block_sizes, flash_attention
 from repro.kernels.rglru.kernel import rglru_pallas
 from repro.kernels.rmsnorm.ops import rms_norm_fused
 from repro.kernels.rwkv6.kernel import wkv6_pallas
@@ -78,6 +78,17 @@ def test_flash_attention_compiles_fwd_and_bwd(arch, one_chip):
     _assert_mosaic(_grad_of_sum(fn, (0, 1, 2)), q, k, k)
 
 
+def test_flash_attention_compiles_at_the_train_cell(one_chip):
+    """GLM-4-9B's attention as the train cell runs it on one v5e: 1 x 4096,
+    32 query heads over 2 K/V heads of 128, with the blocks the shape picks."""
+    assert block_sizes(4096, 4096, 128) == (1024, 1024)
+    fn = functools.partial(flash_attention, causal=True, interpret=False)
+    q = _sds(one_chip, (1, 4096, 32, 128))
+    k = _sds(one_chip, (1, 4096, 2, 128))
+    _assert_mosaic(fn, q, k, k)
+    _assert_mosaic(_grad_of_sum(fn, (0, 1, 2)), q, k, k)
+
+
 @pytest.mark.parametrize("arch", ["llama3.2-1b", "recurrentgemma-9b", "rwkv6-1.6b"])
 def test_fused_rmsnorm_compiles_fwd_and_bwd(arch, one_chip):
     d = get_config(arch).d_model
@@ -108,7 +119,8 @@ def test_wkv6_compiles_at_rwkv6_width(dtype, one_chip):
 
 def test_llama_4_layer_train_step_fits_one_v5e(topo):
     """The chip-smoke training cell: published widths, 4 layers, seq 2048,
-    global batch 2, AdamW state — arguments plus temporaries within HBM."""
+    global batch 2, AdamW state — arguments plus temporaries within HBM, and
+    the default attention lowered to the flash kernel as on the chip."""
     from repro.launch.steps import make_train_step, rules_for
     from repro.models.config import ShapeConfig
 
@@ -127,3 +139,20 @@ def test_llama_4_layer_train_step_fits_one_v5e(topo):
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < V5E_HBM_BYTES, (mem.argument_size_in_bytes, mem.temp_size_in_bytes)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape,want", [((1, 1), "pallas"), ((2, 2), "chunked")])
+def test_default_attention_follows_the_described_chips(topo, shape, want):
+    """From this CPU process a mesh of described v5e chips selects as the chip
+    does: the kernel over one chip's arrays, the chunked scan over four."""
+    from repro.dist.context import use_sharding
+    from repro.launch.steps import rules_for
+    from repro.models.attention import select_impl
+
+    cfg = get_config("llama3.2-1b")
+    n = shape[0] * shape[1]
+    mesh = Mesh(np.asarray(topo.devices[:n]).reshape(shape), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    with use_sharding(mesh, rules_for(cfg)):
+        assert select_impl("auto", SEQ, SEQ) == want
