@@ -112,6 +112,7 @@ def drive(ctx, engine, sess, arrivals, t: dict, dims) -> dict:
     lead, seconds = float(t["lead_s"]), float(ctx.seconds)
     origin = time.monotonic() + 0.01
     open_at, close_at = origin + lead, origin + lead + seconds
+    trace_at = close_at - float(t["trace_seconds"])
     pending = deque(Tracked(a.rid, origin + a.at, a.prompt, a.max_new) for a in arrivals)
     queued: deque[Tracked] = deque()
     active: dict[int, Tracked] = {}
@@ -131,12 +132,12 @@ def drive(ctx, engine, sess, arrivals, t: dict, dims) -> dict:
         if state == "lead" and now >= open_at:
             snap["open"] = (now, spans())
             ctx.compiles.open = True
-            if ctx.tracer is not None:
-                ctx.tracer.start()
             state = "window"
         if state == "window":
-            if ctx.tracer is not None and ctx.tracer.active and now - open_at >= t["trace_seconds"]:
-                ctx.tracer.stop()
+            # the window's last seconds: stopping the profiler holds this loop
+            # for seconds, and at the close that stall falls outside the window
+            if ctx.tracer is not None and not ctx.tracer.active and now >= trace_at:
+                ctx.tracer.start()
             if now >= close_at:
                 ctx.compiles.open = False
                 if ctx.tracer is not None and ctx.tracer.active:

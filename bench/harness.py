@@ -34,7 +34,8 @@ ROOT = Path(__file__).resolve().parents[1]
 #: the checkout, so every run of a checkout finds what its first run compiled
 CACHE_DIR = ROOT / ".bench_cache"
 
-__all__ = ["Cell", "Context", "Outcome", "NoChip", "load_cell", "run_cell", "emit", "main"]
+__all__ = ["Cell", "Context", "Outcome", "NoChip", "load_cell", "draft_cell", "run_cell", "emit",
+           "main"]
 
 
 class NoChip(RuntimeError):
@@ -78,6 +79,20 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
         limits=_read_json(root / "bench" / "limits" / f"{name}.json"),
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],
         per_layer=[m for m in bench["per_layer"] if _applies(m, name)],
+    )
+
+
+def draft_cell(name: str, config: str, traffic: str, chips: int = 1, root: Path = ROOT) -> Cell:
+    """A cell that ``BENCHMARK.json`` does not hold yet, from its files alone
+    (configuration, traffic and limits), with no metrics: for the tools and
+    tests that run a mix before it has bounds."""
+    bench = root / "bench"
+    return Cell(
+        name=name, chips=chips, config_name=config,
+        config=_read_json(bench / "configs" / f"{config}.json"),
+        traffic=_read_json(bench / "traffic" / f"{traffic}.json"),
+        limits=_read_json(bench / "limits" / f"{name}.json"),
+        end_to_end=[], per_layer=[],
     )
 
 
@@ -152,7 +167,12 @@ class Tracer:
         import jax
 
         shutil.rmtree(self.dir, ignore_errors=True)
-        jax.profiler.start_trace(str(self.dir))
+        # ops and spans only: the Python tracer records every call, which
+        # slows a host-bound loop such as the serving engine's several times
+        # over
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(self.dir), profiler_options=options)
         self._ann = jax.profiler.TraceAnnotation("bench/traced_window")
         self._ann.__enter__()
         self.active = True

@@ -38,6 +38,17 @@ def test_every_metric_has_a_reader_and_moves_a_reported_metric():
             assert "workloads" not in moved or cell in moved["workloads"]
 
 
+@pytest.mark.parametrize("name", [w["name"] for w in BENCHMARK["workloads"]])
+def test_every_cell_holds_its_metrics_to_bounds(name):
+    """Each end-to-end metric a cell reports has a bound within the cap, and
+    each of its per-layer metrics moves one of them other than the set-up."""
+    cell = harness.load_cell(name)
+    reported = {m["name"] for m in cell.end_to_end}
+    assert "setup_s" in reported and len(reported) >= 2
+    assert all(0.01 <= m["bound"] <= 0.25 for m in cell.end_to_end)
+    assert all(m["moves"] in reported - {"setup_s"} for m in cell.per_layer)
+
+
 def test_a_cell_added_as_files_alone_is_found(tmp_path):
     """A later PR adds a configuration, a traffic mix, limits and a metric as
     new files plus entries in BENCHMARK.json, and edits no existing file."""
@@ -126,6 +137,38 @@ def test_result_line_has_the_contract_keys(which, tiny_train, tiny_serve, v5e, c
     out, err = capsys.readouterr()
     assert json.loads(out.strip().splitlines()[-1]) == json.loads(json.dumps(result))
     assert err.strip().splitlines()[-1].startswith("check ")
+
+
+def test_a_traced_serve_run_reads_every_serve_metric_but_the_chips(tiny_serve, v5e, tmp_path,
+                                                                     monkeypatch):
+    """Off the chip there is no device plane, so only the device's idle share
+    is left out; every other reader finds its number.  The profiler covers the
+    window's last ``trace_seconds``, so that the seconds its stop holds the
+    loop fall after the close."""
+    import time
+
+    marks = {}
+
+    def spy(name, real):
+        def call(*args, **kwargs):
+            marks[name] = time.monotonic()
+            return real(*args, **kwargs)
+        return call
+
+    monkeypatch.setattr(harness, "CACHE_DIR", tmp_path)
+    monkeypatch.setattr(harness.Tracer, "start", spy("start", harness.Tracer.start))
+    monkeypatch.setattr(harness.Tracer, "stop", spy("stop", harness.Tracer.stop))
+    driver = harness.driver_module(tiny_serve)
+    drive = driver.drive
+    monkeypatch.setattr(driver, "drive", lambda *a: marks.setdefault("rec", drive(*a)))
+    tiny_serve.traffic["trace_seconds"] = 0.3
+    result = harness.run_cell(tiny_serve.name, 2**31 + 17, 1.5, True, require_chip=False,
+                              cell=tiny_serve, peak=v5e)
+    assert set(result["metrics"]) == {m["name"] for m in tiny_serve.per_layer} - {
+        "device_idle_share.serve"}
+    assert all(math.isfinite(m["value"]) for m in result["metrics"].values())
+    close = marks["rec"]["t_close"]
+    assert close - 0.35 <= marks["start"] < marks["stop"] and marks["stop"] >= close
 
 
 @pytest.mark.parametrize("which", ["train", "serve"])

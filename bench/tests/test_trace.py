@@ -1,15 +1,18 @@
-"""The trace reduction, on hand-made events and on a trace recorded on a v5e
-(``bench/record_trace.py``: five steps of a matmul chain, each followed by
-20 ms of host sleep in a ``bench/host_sleep`` span)."""
+"""The trace reduction, on hand-made events and on traces recorded on a v5e
+(``bench/record_trace.py``): five steps of a matmul chain, each followed by
+20 ms of host sleep in a ``bench/host_sleep`` span; and a short window of the
+chat cell's own run at two layers."""
 
 import os
 
 import pytest
 
-from bench.trace import reduce_events, reduce_file, union_length
+from bench import harness
+from bench.trace import WINDOW_SPAN, reduce_events, reduce_file, union_length
 from conftest import ROOT
 
 RECORDED = os.path.join(ROOT, "bench", "testdata", "small.xplane.pb")
+SERVE_RECORDED = os.path.join(ROOT, "bench", "testdata", "serve.xplane.pb")
 
 
 def test_union_of_overlapping_ops():
@@ -54,3 +57,70 @@ def test_recorded_chip_trace():
     assert gaps["bench/host_sleep"] >= 5 * 0.02
     assert r["busy_s"] + sum(gaps.values()) == pytest.approx(r["window_s"], rel=1e-6)
     assert r["device_ops"] and all(s > 0 for _, s in r["device_ops"])
+
+
+def _engine_seconds(path: str) -> tuple[float, float]:
+    """Seconds of the engine's ``serve/prefill`` and ``serve/decode`` spans
+    inside the traced window, from the host plane."""
+    from jax.profiler import ProfileData
+
+    events = [(ev.name, ev.start_ns * 1e-9, (ev.start_ns + ev.duration_ns) * 1e-9)
+              for plane in ProfileData.from_file(path).planes if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events]
+    lo, hi = [(s, e) for n, s, e in events if n == WINDOW_SPAN][-1]
+    inside = lambda name: sum(max(min(e, hi) - max(s, lo), 0.0)  # noqa: E731
+                              for n, s, e in events if n == name)
+    return inside("serve/prefill"), inside("serve/decode")
+
+
+def test_recorded_serve_trace_feeds_the_serve_readers():
+    tr = reduce_file(SERVE_RECORDED, n_devices=1)
+    prefill_s, decode_s = _engine_seconds(SERVE_RECORDED)
+    assert 0 < tr["busy_s"] < tr["window_s"] and decode_s > 0
+    out = harness.Outcome(
+        end_to_end={}, checks=[], attempted=0, failed=0, memory_peak_bytes=0, trace=tr,
+        layer={"kind": "serve", "trace": tr, "window_s": tr["window_s"],
+               "prefill_span_s": prefill_s, "decode_span_s": decode_s},
+    )
+    idle = harness.metric_reader("device_idle_share.serve").read(out)
+    host = harness.metric_reader("engine_host_share.serve").read(out)
+    assert idle is not None and 0 < idle < 100
+    assert host is not None and 0 < host < 100
+
+
+def test_stripping_a_trace_keeps_what_the_readers_read(tmp_path):
+    from jax.profiler import ProfileData
+
+    from bench.record_trace import strip
+
+    with open(RECORDED, "rb") as f:
+        data = f.read()
+    out = tmp_path / "small.xplane.pb"
+    out.write_bytes(strip(data))
+    assert out.stat().st_size < len(data)
+    assert "/host:metadata" in {p.name for p in ProfileData.from_file(RECORDED).planes}
+    assert "/host:metadata" not in {p.name for p in ProfileData.from_file(str(out)).planes}
+    assert reduce_file(str(out), n_devices=1) == reduce_file(RECORDED, n_devices=1)
+    assert strip(out.read_bytes()) == out.read_bytes()
+
+
+def test_the_harness_traces_spans_and_no_python_calls(tmp_path, monkeypatch):
+    """Python's tracer records every call and slows a host-bound loop such as
+    the serving engine's several times over; the spans are what the readers
+    need."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    monkeypatch.setattr(harness, "CACHE_DIR", tmp_path)
+    tracer = harness.Tracer("cell", on_chip=False)
+    tracer.start()
+    with jax.profiler.TraceAnnotation("bench/step"):
+        [str(i) for i in range(100)]
+    tracer.stop()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events}
+    assert {WINDOW_SPAN, "bench/step"} <= names
+    assert not [n for n in names if n.startswith("$")]
