@@ -42,39 +42,23 @@ class Tracked:
     result: object = None
 
 
-def program_tree(flat: dict) -> dict:
-    """The reference's flat leaves in the program's parameter tree."""
-    return {
-        "embed": {"tokens": flat["embed.tokens"]},
-        "final_norm": flat["final_norm"],
-        "lm_head": flat["lm_head"],
-        "scan": ({
-            "ln": flat["ln"], "ln2": flat["ln2"],
-            "mlp": {"wg": flat["mlp.wg"], "wi": flat["mlp.wi"], "wo": flat["mlp.wo"]},
-            "wk": flat["wk"], "wo": flat["wo"], "wq": flat["wq"], "wv": flat["wv"],
-        },),
-        "tail": (),
-    }
-
-
 def build(ctx):
     """(engine, session, cfg, arch, dims) with weights from the seed."""
     import jax
     import jax.numpy as jnp
 
-    from bench import model
-    from bench.harness import reference_module
+    from bench.harness import family_module, reference_module
     from repro.core.params import reset_param_registry
     from repro.serving import ServeSession
     from repro.serving.slo import ServiceLevel
     from repro.timing import TimingSession
 
     cell, t, c = ctx.cell, ctx.cell.traffic, ctx.cell.config
-    R = reference_module(cell)
-    n_layers = model.layers_for(c, t)
-    cfg = model.program_cfg(c, n_layers)
-    arch = model.ref_arch(c, n_layers)
-    params = program_tree(R.make_weights(arch, ctx.key_seed, jnp.bfloat16))
+    R, F = reference_module(cell), family_module(cell)
+    n_layers = F.layers_for(c, t)
+    cfg = F.program_cfg(c, n_layers)
+    arch = F.ref_arch(c, n_layers)
+    params = F.program_tree(R.make_weights(arch, ctx.key_seed, jnp.bfloat16))
     jax.block_until_ready(params)
     sess = TimingSession()
     reset_param_registry()
@@ -82,7 +66,7 @@ def build(ctx):
         cfg, params, session=sess, n_slots=int(t["n_slots"]), max_seq=int(t["max_seq"]),
         block_size=int(t["block_size"]), slo=ServiceLevel(), control=True,
     )
-    return engine, sess, cfg, arch, model.dims(c, n_layers)
+    return engine, sess, cfg, arch, F.dims(c, n_layers)
 
 
 def warm(engine, t: dict, vocab: int, seed: int) -> None:
@@ -106,9 +90,10 @@ def drive(ctx, engine, sess, arrivals, t: dict, dims) -> dict:
     """Run the open loop; returns the window's raw record."""
     import jax
 
-    from bench import counts
+    from bench.harness import family_module
     from repro.serving import Request
 
+    F = family_module(ctx.cell)
     lead, seconds = float(t["lead_s"]), float(ctx.seconds)
     origin = time.monotonic() + 0.01
     open_at, close_at = origin + lead, origin + lead + seconds
@@ -195,7 +180,7 @@ def drive(ctx, engine, sess, arrivals, t: dict, dims) -> dict:
     win_decodes = [a for tb, a in decodes if in_window(tb)]
     decode_flops = decode_ideal = 0.0
     for attended in win_decodes:
-        f, b = counts.decode_step_cost(dims, attended)
+        f, b = F.decode_step_cost(dims, attended)
         decode_flops += f
         decode_ideal += max(f / ctx.peak.bf16_flops, b / ctx.peak.hbm_bytes)
     return {
@@ -204,7 +189,7 @@ def drive(ctx, engine, sess, arrivals, t: dict, dims) -> dict:
         "prefill_span_s": s1[0] - s0[0], "prefill_span_n": s1[1] - s0[1],
         "decode_span_s": s1[2] - s0[2], "decode_span_n": s1[3] - s0[3],
         "prefill_tokens": sum(win_prefills),
-        "prefill_flops": sum(counts.prefill_flops(dims, p) for p in win_prefills),
+        "prefill_flops": sum(F.prefill_flops(dims, p) for p in win_prefills),
         "decode_steps": len(win_decodes), "decode_flops": decode_flops,
         "decode_ideal_s": decode_ideal,
     }

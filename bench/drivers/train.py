@@ -15,13 +15,14 @@ window at a step boundary once the warm-up steps are done, and ends the run
 ``bench/<BIN>`` span into the profiler's trace.
 
 Once the program's state is freed, the float32 reference follows the checked
-steps on the same data and initial weights, and the check compares each
-step's loss, the per-leaf norm of the first (clipped) gradient and the
-per-leaf norm of the master weights' change.
+steps on the same data and initial weights, its layers split over the cell's
+chips, and the check compares each step's loss, the per-leaf norm of the
+first (clipped) gradient and the per-leaf norm of the master weights' change.
 """
 
 from __future__ import annotations
 
+import contextlib
 import gc
 import math
 import statistics
@@ -34,6 +35,8 @@ import numpy as np
 #: comparisons
 NEGLIGIBLE_GRAD = 1e-3
 LOOP_BINS = ("PRESTEP", "EVOL", "ANALYSIS", "CHECKPOINT", "OUTPUT")
+#: the program's counters of the attention layers its step runs by each path
+ATTENTION_COUNTERS = ("attn_flash_layers", "attn_chunked_layers")
 
 
 def tokens_at(seed: int, step: int, batch: int, seq: int, vocab: int):
@@ -45,13 +48,17 @@ def tokens_at(seed: int, step: int, batch: int, seq: int, vocab: int):
     return tokens, targets
 
 
-def leaf_name(path) -> str:
-    """Program parameter path -> reference leaf name (``scan/0/mlp/wg`` ->
-    ``mlp.wg``)."""
-    keys = [str(getattr(k, "key", getattr(k, "idx", k))) for k in path]
-    if keys and keys[0] == "scan":
-        keys = keys[2:]
-    return ".".join(keys)
+def named_norms(F, tree) -> dict:
+    """A tree of per-leaf norms in the program's layout, keyed by the
+    reference's leaf names: the family ``F`` maps the one onto the other."""
+    return {k: float(v) for k, v in F.reference_leaves(tree).items()}
+
+
+def change_norms(F, R, arch, seed: int, master) -> dict:
+    """Per reference leaf, the norm of the program's master weights' change
+    from the initial weights that the reference ``R`` draws."""
+    return {k: float(R.diff_norm(leaf, R.stacked_leaf(arch, seed, k)))
+            for k, leaf in F.reference_leaves(master).items()}
 
 
 def gap(program: float, reference: float, floor: float) -> float:
@@ -78,18 +85,18 @@ def run(ctx):
     import jax
     import jax.numpy as jnp
 
-    from bench import counts, model
-    from bench.harness import Outcome, memory_peak, reference_module
+    from bench.harness import Outcome, family_module, memory_peak, reference_module
+    from repro.core.clocks import counter_values
     from repro.core.params import reset_param_registry
     from repro.launch.train import TrainSettings, run_training
     from repro.timing import TimingSession
 
     cell, t, c = ctx.cell, ctx.cell.traffic, ctx.cell.config
-    R = reference_module(cell)
+    R, F = reference_module(cell), family_module(cell)
     stages = int(t.get("pipeline_stages", 0))
-    n_layers = model.layers_for(c, t)
-    cfg = model.program_cfg(c, n_layers)
-    arch = model.ref_arch(c, n_layers)
+    n_layers = F.layers_for(c, t)
+    cfg = F.program_cfg(c, n_layers)
+    arch = F.ref_arch(c, n_layers)
     seed = ctx.key_seed
     batch, seq = int(t["global_batch"]), int(t["seq_len"])
     n_check, n_warm = int(t["check_steps"]), int(t["warmup_steps"])
@@ -110,16 +117,6 @@ def run(ctx):
     def grad_norms(m):
         # the optimizer's first moment after one step is (1 - b1) * g
         return jax.tree.map(lambda x: jnp.sqrt(jnp.sum(jnp.square(x))) / (1 - opt.b1), m)
-
-    def named(tree) -> dict:
-        flat = jax.tree_util.tree_flatten_with_path(tree)[0]
-        return {leaf_name(p): float(v) for p, v in flat}
-
-    def change_norms(master) -> dict:
-        return {
-            leaf_name(path): float(R.diff_norm(leaf, R.stacked_leaf(arch, seed, leaf_name(path))))
-            for path, leaf in jax.tree_util.tree_flatten_with_path(master)[0]
-        }
 
     step_timer = "EVOL/trainer::train_step"
 
@@ -147,9 +144,9 @@ def run(ctx):
             tokens, _ = tokens_at(seed, i, batch, seq, cfg.vocab_size)
             st["fed_differ"] += int(not np.array_equal(np.asarray(s["batch"]["tokens"]), tokens))
             if i == 0:
-                st["grad_norms"] = named(grad_norms(s["opt_state"]["m"]))
+                st["grad_norms"] = named_norms(F, grad_norms(s["opt_state"]["m"]))
             if i == n_check - 1:
-                st["change_norms"] = change_norms(s["opt_state"]["master"])
+                st["change_norms"] = change_norms(F, R, arch, seed, s["opt_state"]["master"])
         if i == n_warm - 1:
             open_window(s)
             return
@@ -191,11 +188,17 @@ def run(ctx):
                                      if r.name != f"begin_{b}"))
 
     sch.schedule(schedule_ends, bin="INITIAL", thorn="bench")
-    if ctx.fault:
-        _plant(ctx.fault, sch, st)
 
     reset_param_registry()
-    run_training(settings, cfg=cfg, session=sess)
+    before = counter_values(ATTENTION_COUNTERS)
+    try:
+        with _planted(ctx.fault, sch, st):
+            run_training(settings, cfg=cfg, session=sess)
+    finally:
+        # counted at start-up, so a run that fails later reports them too
+        ctx.log("attention layers by path: " + ", ".join(
+            f"{n} {a - b:g}" for n, a, b in
+            zip(ATTENTION_COUNTERS, counter_values(ATTENTION_COUNTERS), before)))
     peak_bytes = memory_peak(ctx.devices)
     # the scheduler's routines hold the program's run state (parameters and
     # optimizer state): drop the session so they are gone before the reference
@@ -211,7 +214,7 @@ def run(ctx):
     # -- the reference, once the program's state is gone --------------------------
     t_ref = time.perf_counter()
     batches = [tokens_at(seed, i, batch, seq, cfg.vocab_size) for i in range(n_check)]
-    ref = R.train_reference(arch, seed, batches, opt)
+    ref = R.train_reference(arch, seed, batches, opt, devices=ctx.devices)
     checks = compare(st, ref)
     for ch in checks:
         ch["limit"] = float(cell.limits[ch["name"]])
@@ -219,10 +222,10 @@ def run(ctx):
             f"{st['losses']} reference {ref['losses']}")
     notes = {}
     if ctx.control:
-        low = R.train_reference(arch, seed, batches, opt, precision="fp8")
+        low = R.train_reference(arch, seed, batches, opt, precision="fp8", devices=ctx.devices)
         notes["control"] = {ch["name"]: ch["value"] for ch in compare({**low, "fed_differ": 0}, ref)}
 
-    dims = model.dims(c, n_layers)
+    dims = F.dims(c, n_layers)
     span_s = st["span1"][0] - st["span0"][0]
     span_n = st["span1"][1] - st["span0"][1]
     return Outcome(
@@ -236,7 +239,7 @@ def run(ctx):
             "steps": st["steps"],
             "step_span_s": span_s,
             "step_span_n": span_n,
-            "model_flops": st["steps"] * counts.train_step_flops(dims, seq, batch),
+            "model_flops": st["steps"] * F.train_step_flops(dims, seq, batch),
             "chips": len(ctx.devices),
             "peak_flops": ctx.peak.bf16_flops,
             "trace": trace,
@@ -251,12 +254,20 @@ def run(ctx):
     )
 
 
-def _plant(fault: str, sch, st) -> None:
-    """Break the timed path underneath the harness (tests only)."""
+#: the hand-off fault: this stage receives zeros in place of this microbatch's
+#: activations, in every step
+HANDOFF_STAGE, HANDOFF_MICRO = 1, 0
+
+
+@contextlib.contextmanager
+def _planted(fault: str | None, sch, st):
+    """Break the timed path underneath the harness (tests and readings only)."""
     import jax
     import jax.numpy as jnp
 
-    if fault == "unchanged_state":
+    if fault is None:
+        yield
+    elif fault == "unchanged_state":
         def snap(s):
             st["snap"] = jax.tree.map(jnp.copy, (s["params"], s["opt_state"]))
 
@@ -266,6 +277,7 @@ def _plant(fault: str, sch, st) -> None:
         sch.schedule(snap, bin="EVOL", thorn="fault", before=("train_step",))
         sch.schedule(restore, bin="EVOL", thorn="fault", after=("train_step",),
                      before=("after_step",))
+        yield
     elif fault == "half_batch":
         def drop_half(s):
             y = s["batch"]["targets"]
@@ -275,5 +287,58 @@ def _plant(fault: str, sch, st) -> None:
                 s["batch"]["targets"] = y.at[:, y.shape[1] // 2:].set(-1)
 
         sch.schedule(drop_half, bin="PRESTEP", thorn="fault", after=("fetch_data",))
+        yield
+    elif fault == "handoff":
+        with _zeroed_handoff(HANDOFF_STAGE, HANDOFF_MICRO):
+            yield
     else:
         raise ValueError(f"unknown fault {fault!r}")
+
+
+@contextlib.contextmanager
+def _zeroed_handoff(stage: int, micro: int):
+    """While the 1F1B schedule is traced, wrap its forward ``ppermute`` (the
+    ring ``d -> d + 1``) so that ``stage`` receives zeros in place of
+    microbatch ``micro``: stage ``d`` receives at tick ``t + 1`` what stage
+    ``d - 1`` sent at tick ``t``, microbatch ``t + 1 - d``.  (With two
+    stages the backward ring is the same pairs, and is broken alike.)
+
+    The tick loop is found by the program's own name for its body
+    (``shard_body.<locals>.tick`` in ``repro.dist.pipeline``): if the program
+    renames it, the fault finds no loop and raises."""
+    import jax
+    import jax.numpy as jnp
+
+    real_loop, real_permute = jax.lax.fori_loop, jax.lax.ppermute
+    tick: dict = {}
+    hits = []
+
+    def fori_loop(lower, upper, body, init, **kwargs):
+        if not getattr(body, "__qualname__", "").endswith("shard_body.<locals>.tick"):
+            return real_loop(lower, upper, body, init, **kwargs)
+
+        def traced(t, c):
+            tick["t"] = t
+            try:
+                return body(t, c)
+            finally:
+                tick.clear()
+
+        return real_loop(lower, upper, traced, init, **kwargs)
+
+    def ppermute(x, axis_name, perm):
+        y = real_permute(x, axis_name, perm)
+        forward = all(dst == (src + 1) % len(perm) for src, dst in perm)
+        if "t" not in tick or not forward:
+            return y
+        d = jax.lax.axis_index(axis_name)
+        hits.append(1)
+        return jnp.where((d == stage) & (tick["t"] + 1 - d == micro), jnp.zeros_like(y), y)
+
+    jax.lax.fori_loop, jax.lax.ppermute = fori_loop, ppermute
+    try:
+        yield
+    finally:
+        jax.lax.fori_loop, jax.lax.ppermute = real_loop, real_permute
+    if not hits:
+        raise RuntimeError("the hand-off fault found no 1F1B tick loop to break")

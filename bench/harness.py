@@ -4,7 +4,9 @@ Everything that belongs to one cell is found by name:
 
 * the cell, its end-to-end and per-layer metrics: ``BENCHMARK.json``;
 * its configuration: the ``file`` of its ``configs`` entry
-  (``bench/configs/<config>.json``), which names the plain reference module
+  (``bench/configs/<config>.json``), whose ``reference`` key names its block
+  family (``bench/families/<reference>.py``: the keys, the program's and the
+  reference's configs, the counts) and the plain reference module
   (``bench/reference/<reference>.py``);
 * its traffic: ``bench/traffic/<traffic>.json``, which names the driver that
   reads it (``bench/drivers/<driver>.py``);
@@ -34,8 +36,8 @@ ROOT = Path(__file__).resolve().parents[1]
 #: the checkout, so every run of a checkout finds what its first run compiled
 CACHE_DIR = ROOT / ".bench_cache"
 
-__all__ = ["Cell", "Context", "Outcome", "NoChip", "load_cell", "draft_cell", "run_cell", "emit",
-           "main"]
+__all__ = ["Cell", "Context", "Outcome", "NoChip", "load_cell", "draft_cell", "family_module",
+           "run_cell", "emit", "main"]
 
 
 class NoChip(RuntimeError):
@@ -84,14 +86,15 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
 
 def draft_cell(name: str, config: str, traffic: str, chips: int = 1, root: Path = ROOT) -> Cell:
     """A cell that ``BENCHMARK.json`` does not hold yet, from its files alone
-    (configuration, traffic and limits), with no metrics: for the tools and
-    tests that run a mix before it has bounds."""
+    (configuration, traffic and, once they are set, limits), with no metrics:
+    for the tools and tests that run a mix before it has bounds."""
     bench = root / "bench"
+    limits = bench / "limits" / f"{name}.json"
     return Cell(
         name=name, chips=chips, config_name=config,
         config=_read_json(bench / "configs" / f"{config}.json"),
         traffic=_read_json(bench / "traffic" / f"{traffic}.json"),
-        limits=_read_json(bench / "limits" / f"{name}.json"),
+        limits=_read_json(limits) if limits.is_file() else {},
         end_to_end=[], per_layer=[],
     )
 
@@ -118,6 +121,15 @@ def metric_reader(name: str, root: Path = ROOT):
 
 def reference_module(cell: Cell):
     return importlib.import_module(f"bench.reference.{cell.config['reference']}")
+
+
+def family_module(cell: Cell, root: Path = ROOT):
+    """The configuration's block family, ``bench/families/<reference>.py``."""
+    family = cell.config["reference"]
+    path = root / "bench" / "families" / f"{family}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"{cell.config_name} names the family {family!r}: no {path}")
+    return _load_file(path, f"bench.families.{family}")
 
 
 def jax_seed(seed: int) -> int:

@@ -1,6 +1,6 @@
 """The decode steps' least possible time, each max(FLOPs / peak, bytes / HBM
 bandwidth) with the bytes the step needs (weights, the KV of each active
-row's real positions; ``bench/counts.decode_step_cost``), over the seconds in
+row's real positions; the family's ``decode_step_cost``), over the seconds in
 ``serve/decode``.  Left out where the driver's account of the rows disagrees
 with a finished request."""
 

@@ -1,5 +1,6 @@
-"""Prefill FLOPs of the window (``bench/counts.prefill_flops``) over the seconds
-in ``serve/prefill`` x the chip's bf16 peak."""
+"""Prefill FLOPs of the window (the family's ``prefill_flops``,
+``bench/families``) over the seconds in ``serve/prefill`` x the chip's bf16
+peak."""
 
 
 def read(outcome):

@@ -1,6 +1,6 @@
-"""Model FLOPs of the window's steps (``bench/counts.train_step_flops``: 6 x
-layer and head params x tokens plus causal attention, no recomputation) over
-window x chips x the chip's bf16 peak."""
+"""Model FLOPs of the window's steps (the family's ``train_step_flops`` in
+``bench/families``: 6 x layer and head params x tokens plus causal attention,
+no recomputation) over window x chips x the chip's bf16 peak."""
 
 
 def read(outcome):

@@ -14,7 +14,9 @@ It follows the layer equations of the block the benchmark runs, written out in
     loss = mean(lse - logit[target]) + 1e-4 * mean(lse^2)
 
 and AdamW with global-norm clipping, bias correction, decoupled weight decay
-on an f32 master copy, and the linear-warmup cosine learning rate.  Where
+on an f32 master copy, and the linear-warmup cosine learning rate.  Training
+runs layer by layer over one sequence at a time, with the layers split over
+the cell's chips where one chip cannot hold their f32 state.  Where
 this block departs from the published GLM-4-9B layer (no QKV bias, full-head
 rotate-half RoPE) the configuration file lists it under ``departures``.
 
@@ -59,6 +61,8 @@ __all__ = [
     "top_weights",
     "loss_fn",
     "train_reference",
+    "adamw_update",
+    "clip_scale",
     "served_token_gaps",
     "lr_at",
     "diff_norm",
@@ -302,53 +306,182 @@ def diff_norm(x, y):
     return jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32) - y)))
 
 
-def _norms(tree: dict) -> dict:
-    return {k: jnp.sqrt(jnp.sum(jnp.square(v))) for k, v in tree.items()}
+def _rounded(w: dict) -> dict:
+    # the program computes in bf16 from its f32 master: the loss sees the
+    # master rounded to bf16, and the gradient flows back through the
+    # rounding, which rounds it to bf16 in turn (see ``_through_rounding``)
+    return {k: x.astype(jnp.bfloat16).astype(jnp.float32) for k, x in w.items()}
 
 
-def train_reference(a: Arch, seed: int, batches, opt: AdamW, precision: str = "f32"):
+#: the gradient's way back through ``_rounded``: each sequence's gradient is
+#: taken for the rounded weights and the sum over the batch rounded once, as
+#: differentiating the whole batch's loss through the rounding rounds it
+_through_rounding = jax.jit(_rounded, donate_argnums=(0,))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _layer_forward(a: Arch, precision: str, w: dict, h):
+    return layer_apply(a, _rounded(w), h, precision)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _layer_backward(a: Arch, precision: str, w: dict, h, g):
+    """(gradient for the rounded weights, input gradient) of one layer,
+    recomputed from its input."""
+    _, vjp = jax.vjp(lambda w, h: layer_apply(a, w, h, precision), _rounded(w), h)
+    return vjp(g)
+
+
+@jax.jit
+def _embed(w, tokens):
+    return w.astype(jnp.bfloat16).astype(jnp.float32)[tokens]
+
+
+@jax.jit
+def _embed_backward(w, tokens, g):
+    """The gradient for the rounded embedding of one sequence's ``tokens``."""
+    return jnp.zeros_like(w).at[tokens].add(g)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _head(a: Arch, precision: str, top: dict, h, targets, n_valid):
+    """One sequence's share of the batch loss (its CE and z-loss terms over the
+    batch's ``n_valid`` targets), with its gradients for the rounded head and
+    for h."""
+    def share(top, h):
+        logits = _logits(a, top, h, precision)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, jnp.maximum(targets, 0)[:, None], axis=-1)[:, 0]
+        valid = (targets >= 0).astype(jnp.float32)
+        return (jnp.sum((lse - gold) * valid) + Z_COEF * jnp.sum(lse * lse * valid)) / n_valid
+
+    return jax.value_and_grad(share, argnums=(0, 1))(_rounded(top), h)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _add(acc: dict, g: dict) -> dict:
+    return {k: acc[k] + g[k] for k in acc}
+
+
+@jax.jit
+def _sumsq(tree: dict) -> dict:
+    return {k: jnp.sum(jnp.square(x)) for k, x in tree.items()}
+
+
+@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1, 2, 3))
+def adamw_update(opt: AdamW, master: dict, m: dict, v: dict, g: dict, scale, step, lr):
+    """AdamW on some of the leaves: ``scale`` clips by the global norm, which
+    the caller takes over all of them; bias correction, decoupled decay."""
+    g = {k: x * scale for k, x in g.items()}
+    c1, c2 = 1 - opt.b1 ** step, 1 - opt.b2 ** step
+    m = {k: opt.b1 * m[k] + (1 - opt.b1) * g[k] for k in g}
+    v = {k: opt.b2 * v[k] + (1 - opt.b2) * g[k] ** 2 for k in g}
+    master = {
+        k: master[k] - lr * ((m[k] / c1) / (jnp.sqrt(v[k] / c2) + opt.eps)
+                             + opt.weight_decay * master[k])
+        for k in g
+    }
+    return master, m, v
+
+
+def clip_scale(opt: AdamW, gnorm: float) -> float:
+    return min(1.0, opt.clip_norm / max(gnorm, 1e-12))
+
+
+def train_reference(a: Arch, seed: int, batches, opt: AdamW, precision: str = "f32",
+                    devices=None):
     """Follow ``len(batches)`` steps from the ``stacked``-rule weights.
+
+    The layers are split over ``devices`` (default: JAX's first) in contiguous
+    groups of equal size, the embedding on the first and the final norm and
+    head on the last: each device holds its leaves' f32 master, AdamW moments
+    and gradient sum.  A step runs every sequence forward layer by layer,
+    keeping each layer's input on its device, then every sequence backward
+    layer by layer, recomputing each layer from its input, and adds each
+    sequence's gradient into the sums.  There is no pipeline schedule: the
+    devices overlap only as far as JAX's asynchronous dispatch lets them.
 
     Returns the loss of each step, the per-leaf norm of the first gradient as
     the optimizer takes it (after clipping), and the per-leaf norm of the
     master weights' change after the last step.
     """
+    devices = list(devices) if devices else jax.devices()[:1]
+    owner = {i: devices[i * len(devices) // a.n_layers] for i in range(a.n_layers)}
+    parts = {("layer", i): (owner[i], _STACKED) for i in range(a.n_layers)}
+    parts["embed"] = (devices[0], ("embed.tokens",))
+    parts["head"] = (devices[-1], ("final_norm", "lm_head"))
+
+    def per_part(fn):
+        """{part: {leaf: fn(part, leaf, its initial value on the part's
+        device)}}, one whole leaf drawn at a time."""
+        out = {p: {} for p in parts}
+        for name, stacked, _ in LEAVES:
+            w = stacked_leaf(a, seed, name)
+            for p, (dev, names) in parts.items():
+                if name in names:
+                    out[p][name] = fn(p, name, jax.device_put(w[p[1]] if stacked else w, dev))
+            del w
+        return out
+
     with jax.default_matmul_precision("highest"):
-        # the program computes in bf16 from its f32 master: the loss sees the
-        # master rounded to bf16, and the gradient flows through the rounding
-        grad_fn = jax.jit(jax.value_and_grad(lambda w, t, y: loss_fn(
-            a, {k: x.astype(jnp.bfloat16).astype(jnp.float32) for k, x in w.items()},
-            t, y, precision)))
-
-        @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
-        def update(master, m, v, g, step, lr):
-            gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(x)) for x in g.values()))
-            scale = jnp.minimum(1.0, opt.clip_norm / jnp.maximum(gnorm, 1e-12))
-            g = {k: x * scale for k, x in g.items()}
-            c1, c2 = 1 - opt.b1 ** step, 1 - opt.b2 ** step
-            m = {k: opt.b1 * m[k] + (1 - opt.b1) * g[k] for k in g}
-            v = {k: opt.b2 * v[k] + (1 - opt.b2) * g[k] ** 2 for k in g}
-            master = {
-                k: master[k] - lr * ((m[k] / c1) / (jnp.sqrt(v[k] / c2) + opt.eps)
-                                     + opt.weight_decay * master[k])
-                for k in g
-            }
-            return master, m, v, _norms(g)
-
-        master = stacked_weights(a, seed)
-        m = {k: jnp.zeros_like(x) for k, x in master.items()}
-        v = {k: jnp.zeros_like(x) for k, x in master.items()}
+        master = per_part(lambda p, name, w: w)
+        m = {p: {k: jnp.zeros_like(x) for k, x in leaves.items()} for p, leaves in master.items()}
+        v = {p: {k: jnp.zeros_like(x) for k, x in leaves.items()} for p, leaves in master.items()}
         losses, first_grad = [], None
-        for i, (tokens, targets) in enumerate(batches):
-            loss, g = grad_fn(master, jnp.asarray(tokens), jnp.asarray(targets))
-            losses.append(float(loss))
-            master, m, v, gn = update(master, m, v, g, i + 1, lr_at(opt, i))
-            del g
+        for step, (tokens, targets) in enumerate(batches):
+            n_valid = float(max(int((np.asarray(targets) >= 0).sum()), 1))
+            gsum = {p: None for p in parts}
+
+            def accumulate(p, g):
+                gsum[p] = g if gsum[p] is None else _add(gsum[p], g)
+
+            # forward, every sequence; the head's gradient seeds the backward
+            stash, seeds, shares = {}, {}, []
+            for b in range(len(tokens)):
+                tok = jax.device_put(jnp.asarray(tokens[b]), devices[0])
+                h = _embed(master["embed"]["embed.tokens"], tok)
+                for i in range(a.n_layers):
+                    h = stash[b, i] = jax.device_put(h, owner[i])
+                    h = _layer_forward(a, precision, master["layer", i], h)
+                tgt = jax.device_put(jnp.asarray(targets[b]), devices[-1])
+                share, (g_top, seeds[b]) = _head(a, precision, master["head"],
+                                                 jax.device_put(h, devices[-1]), tgt, n_valid)
+                shares.append(share)
+                accumulate("head", g_top)
+            # backward, every sequence
+            for b in range(len(tokens)):
+                g = seeds.pop(b)
+                for i in reversed(range(a.n_layers)):
+                    gw, g = _layer_backward(a, precision, master["layer", i], stash.pop((b, i)),
+                                            jax.device_put(g, owner[i]))
+                    accumulate(("layer", i), gw)
+                tok = jax.device_put(jnp.asarray(tokens[b]), devices[0])
+                accumulate("embed", {"embed.tokens": _embed_backward(
+                    master["embed"]["embed.tokens"], tok, jax.device_put(g, devices[0]))})
+            losses.append(float(sum(float(x) for x in shares)))
+
+            gsum = {p: _through_rounding(g) for p, g in gsum.items()}
+            sq = {p: {k: float(x) for k, x in _sumsq(g).items()} for p, g in gsum.items()}
+            scale = clip_scale(opt, math.sqrt(sum(x for leaves in sq.values()
+                                                  for x in leaves.values())))
             if first_grad is None:
-                first_grad = {k: float(x) for k, x in gn.items()}
+                first_grad = {
+                    name: scale * math.sqrt(sum(leaves[name] for leaves in sq.values()
+                                                if name in leaves))
+                    for name, _, _ in LEAVES
+                }
+            for p in parts:
+                master[p], m[p], v[p] = adamw_update(opt, master[p], m[p], v[p], gsum.pop(p),
+                                                     scale, step + 1, lr_at(opt, step))
         del m, v
-        change = {k: float(diff_norm(x, stacked_leaf(a, seed, k))) for k, x in master.items()}
-    return {"losses": losses, "grad_norms": first_grad, "change_norms": change}
+        change = per_part(lambda p, name, w: float(diff_norm(master[p][name], w)) ** 2)
+    return {
+        "losses": losses,
+        "grad_norms": first_grad,
+        "change_norms": {name: math.sqrt(sum(leaves[name] for leaves in change.values()
+                                              if name in leaves))
+                         for name, _, _ in LEAVES},
+    }
 
 
 # ---------------------------------------------------------------------------

@@ -24,11 +24,15 @@ TINY = {
 #: the limits at that size, set as the cells' are, from CPU readings there
 #: (largest of 12 sound seeds, smallest of the fp8 control on 3: loss 3.86e-4
 #: and 2.20e-3, grad 2.26e-3 and 1.08e-2, change 8.48e-4 and 3.12e-3, token
-#: 1.75e-2 and 0.138); the gaps shrink as the widths grow, so the cells'
-#: own limits are tighter
+#: 1.75e-2 and 0.138; the four-chip cell's 8 layers on four virtual devices,
+#: seeds 3000000000-11 of ``four_devices.py readings``: loss 2.42e-4 and
+#: 8.31e-4, grad 2.31e-3 and 9.52e-3, change 7.88e-4 and 2.60e-3); the gaps
+#: shrink as the widths grow, so the cells' own limits are tighter
 TINY_LIMITS = {
     "train.glm4-9b.4k": {"loss_gap": 1e-3, "grad_gap": 5e-3, "change_gap": 1.6e-3,
                          "fed_tokens_differ": 0.0},
+    "train.glm4-9b.pp4": {"loss_gap": 5e-4, "grad_gap": 5e-3, "change_gap": 1.6e-3,
+                          "fed_tokens_differ": 0.0},
     "serve.glm4-9b.chat": {"token_gap": 0.05},
 }
 
@@ -58,11 +62,32 @@ def serve_cell():
     return cell
 
 
+#: the per-layer metrics the four-chip cell would report beside the train
+#: cell's own
+PP4_READERS = ("stage_busy_spread.train", "handoff_share.train")
+
+
+def pp4_cell():
+    """The four-chip 1F1B cell from its files alone: it is not a cell of
+    BENCHMARK.json, since the program cannot start it on four v5e chips
+    (PERF.md, Open questions), but its driver path, split reference, faults
+    and readers are tested here all the same."""
+    from bench.harness import draft_cell
+
+    cell = draft_cell("train.glm4-9b.pp4", "glm4-9b-train", "pretrain-4k-pp4", chips=4)
+    cell.end_to_end = [{"name": "train_tokens_per_s", "unit": "tokens/s"},
+                       {"name": "setup_s", "unit": "s"}]
+    cell.per_layer = [{"name": n, "unit": "%"} for n in PP4_READERS]
+    return cell
+
+
 def tiny_cell(name: str, **traffic):
-    """A cell at tiny widths and lengths: one of BENCHMARK.json, or the chat cell."""
+    """A cell at tiny widths and lengths: one of BENCHMARK.json, the chat cell
+    or the four-chip cell."""
     from bench.harness import load_cell
 
-    cell = serve_cell() if name == "serve.glm4-9b.chat" else load_cell(name)
+    drafts = {"serve.glm4-9b.chat": serve_cell, "train.glm4-9b.pp4": pp4_cell}
+    cell = drafts[name]() if name in drafts else load_cell(name)
     cell.config = dict(cell.config, **TINY, num_layers=2)
     cell.traffic = dict(cell.traffic, **traffic)
     cell.limits = TINY_LIMITS[name]

@@ -1,8 +1,10 @@
-"""The counts and the peak table against hand counts at a tiny size."""
+"""The dense family's counts and the peak table against hand counts at a tiny
+size, and its numbers for the benchmark's configurations as they stood before
+the counts moved into ``bench/families``."""
 
 import pytest
 
-from bench.counts import Dims, decode_step_cost, prefill_flops, train_step_flops
+from bench.families.dense_gqa import Dims, decode_step_cost, prefill_flops, train_step_flops
 from bench.peaks import peak_for
 
 # d 64, 4 heads of 16, 2 K/V heads, MLP 128, vocab 256, 2 layers
@@ -56,3 +58,26 @@ def test_peaks_known_and_unknown():
     assert peak_for("TPU v5 lite").hbm_bytes == 819e9
     with pytest.raises(KeyError, match="no published peak"):
         peak_for("TPU v9 imaginary")
+
+
+def _family(config: str):
+    from bench.harness import draft_cell, family_module
+
+    cell = draft_cell("train.glm4-9b.4k", config, "pretrain-4k")
+    return cell.config, family_module(cell)
+
+
+@pytest.mark.parametrize("stages, batch, flops", [(0, 1, 12756254195712), (4, 8, 362432962756608)])
+def test_train_flops_of_the_train_configuration(stages, batch, flops):
+    config, F = _family("glm4-9b-train")
+    n_layers = F.layers_for(config, {"pipeline_stages": stages})
+    assert n_layers == 2 * max(stages, 1)
+    assert F.train_step_flops(F.dims(config, n_layers), 4096, batch) == flops
+
+
+def test_serve_counts_of_the_serve_configuration():
+    config, F = _family("glm4-9b-serve")
+    dims = F.dims(config, F.layers_for(config, {}))
+    assert F.prefill_flops(dims, 2048) == 17396194607104
+    assert F.prefill_flops(dims, 256) == 2100448722944
+    assert F.decode_step_cost(dims, [1000, 2000, 2568]) == (30022828032, 9514799104)

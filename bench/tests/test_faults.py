@@ -3,8 +3,9 @@
 Each test skips the harness's look for a chip and drives the rest of a run
 at a tiny size with one fault planted: a step that returns its state
 unchanged, half of the batch left out, a served token altered where it is
-produced.  (The cells here run on one chip, so no exchange between chips can
-be left out.)  The limits are those set at that size (``conftest.TINY_LIMITS``).
+produced.  The four-chip cell's faults, the exchange between chips among
+them, are in ``test_pipeline_cell.py``.  The limits are those set at that size
+(``conftest.TINY_LIMITS``).
 """
 
 import pytest

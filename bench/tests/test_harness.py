@@ -23,6 +23,7 @@ def test_every_cell_resolves_from_data(name):
     assert cell.config["name"] == cell.config_name
     assert harness.driver_module(cell).run
     assert harness.reference_module(cell).served_token_gaps
+    assert harness.family_module(cell).train_step_flops
     assert cell.end_to_end and cell.per_layer
     assert "setup_s" in [m["name"] for m in cell.end_to_end]
     for m in cell.per_layer:
@@ -85,6 +86,89 @@ def test_a_cell_added_as_files_alone_is_found(tmp_path):
     assert {m["name"] for m in cell.end_to_end} == {"ttft_p95_ms", "tpot_p95_ms", "setup_s"}
     assert harness.driver_module(cell, root=tmp_path).run
     assert harness.metric_reader("fixture_metric", root=tmp_path).read(None) == 42.0
+
+
+def test_a_family_that_does_not_exist_fails_with_its_path(tmp_path):
+    """A configuration names its block family; the harness looks for
+    ``bench/families/<family>.py`` and says where it looked."""
+    cell = harness.draft_cell("train.glm4-9b.4k", "glm4-9b-train", "pretrain-4k")
+    cell.config = dict(cell.config, reference="no_such_block")
+    with pytest.raises(FileNotFoundError) as e:
+        harness.family_module(cell, root=tmp_path)
+    assert str(tmp_path / "bench" / "families" / "no_such_block.py") in str(e.value)
+    with pytest.raises(FileNotFoundError, match="families/no_such_block.py"):
+        harness.family_module(cell)
+
+
+#: a family of three block kinds and a tail, as a later configuration adds it:
+#: it names each group of the program's stack (``scan/<i>``, ``tail/<i>``)
+TWO_KINDS_FAMILY = '''
+import jax
+
+GROUPS, TAIL = ("recurrent", "local_a", "local_b"), ("last",)
+
+
+def _named(prefix, tree):
+    return {prefix + ".".join(str(getattr(k, "key", k)) for k in path): leaf
+            for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+def reference_leaves(tree):
+    out = _named("", {k: v for k, v in tree.items() if k not in ("scan", "tail")})
+    for group, sub in zip(GROUPS, tree["scan"], strict=True):
+        out.update(_named(group + ".", sub))
+    for group, sub in zip(TAIL, tree["tail"], strict=True):
+        out.update(_named(group + ".", sub))
+    return out
+'''
+
+
+def test_a_family_of_two_block_kinds_keys_the_train_norms_by_its_leaf_names(tmp_path):
+    """A family written as a new file maps a stack of several block kinds with
+    a tail onto its reference's leaf names, and the train driver's gradient
+    and weight-change norms come out keyed by those names, one per leaf of
+    the program's tree; the drivers name no family."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+
+    from bench.drivers import serve, train
+    from bench.reference import dense_gqa
+    from repro.configs import get_smoke_config
+    from repro.models import model as M
+
+    (tmp_path / "bench" / "families").mkdir(parents=True)
+    (tmp_path / "bench" / "families" / "two_kinds.py").write_text(TWO_KINDS_FAMILY)
+    cell = harness.draft_cell("train.glm4-9b.4k", "glm4-9b-train", "pretrain-4k")
+    cell.config = dict(cell.config, reference="two_kinds")
+    F = harness.family_module(cell, root=tmp_path)
+
+    cfg = get_smoke_config("recurrentgemma-9b")      # rglru, attn_local x 2; 4 layers
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    assert len(params["scan"]) == 3 and len(params["tail"]) == 1
+    by_name = F.reference_leaves(params)
+    assert len(by_name) == len(jax.tree.leaves(params))
+    assert {k.split(".")[0] for k in by_name} >= {"recurrent", "local_a", "local_b", "last"}
+
+    def norm(x):
+        return float(jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)))))
+
+    grads = train.named_norms(F, jax.tree.map(norm, params))
+    assert grads == {k: norm(x) for k, x in by_name.items()}
+    assert grads["local_a.wq"] != grads["local_b.wq"]
+
+    master = jax.tree.map(lambda x: x.astype(jnp.float32) * 3, params)
+    R = types.SimpleNamespace(diff_norm=dense_gqa.diff_norm,
+                              stacked_leaf=lambda a, seed, k: by_name[k].astype(jnp.float32))
+    change = train.change_norms(F, R, None, 0, master)
+    assert sorted(change) == sorted(by_name)
+    for k, x in by_name.items():
+        assert change[k] == pytest.approx(2 * norm(x), rel=1e-5), k
+
+    for driver in (train, serve):
+        source = open(driver.__file__).read()
+        assert "dense_gqa" not in source and "bench.families" not in source
 
 
 def test_serve_traffic_is_a_function_of_the_seed():

@@ -9,15 +9,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import model
-from bench.drivers.serve import program_tree
-from bench.drivers.train import leaf_name, tokens_at
+from bench.drivers.train import tokens_at
+from bench.families import dense_gqa as F
 from bench.reference import dense_gqa as R
 from conftest import TINY, TINY_LIMITS
 
 CONFIG = dict(TINY, num_layers=2, layernorm_epsilon=1e-5, rope_ratio=1, program_arch="glm4-9b",
               name="tiny")
-ARCH = model.ref_arch(CONFIG, 2)
+ARCH = F.ref_arch(CONFIG, 2)
 SEED = 1234
 
 
@@ -29,7 +28,7 @@ def M():
 
 
 def program_cfg(dtype="float32"):
-    return model.program_cfg(CONFIG, 2).replace(dtype=dtype, attn_chunk=8)
+    return F.program_cfg(CONFIG, 2).replace(dtype=dtype, attn_chunk=8)
 
 
 # a one-bfloat16-step difference between a jitted draw and the program's is
@@ -38,11 +37,11 @@ def program_cfg(dtype="float32"):
 def test_stacked_rule_is_the_programs_init(M, seed):
     got = R.stacked_weights(ARCH, seed)
     want = M.init_params(program_cfg("bfloat16"), jax.random.PRNGKey(seed))
-    flat = jax.tree_util.tree_flatten_with_path(want)[0]
-    assert sorted(leaf_name(p) for p, _ in flat) == sorted(got)
-    for path, leaf in flat:
-        np.testing.assert_array_equal(np.asarray(leaf, np.float32),
-                                      np.asarray(got[leaf_name(path)]), err_msg=leaf_name(path))
+    flat = F.reference_leaves(want)
+    assert sorted(flat) == sorted(got)
+    for name, leaf in flat.items():
+        np.testing.assert_array_equal(np.asarray(leaf, np.float32), np.asarray(got[name]),
+                                      err_msg=name)
 
 
 @pytest.mark.parametrize("seed", [SEED, 7])
@@ -60,7 +59,7 @@ def test_per_layer_rule_is_what_the_reference_rebuilds(seed):
 
 def test_program_tree_matches_the_programs_params(M):
     flat = R.make_weights(ARCH, SEED, jnp.bfloat16)
-    tree = program_tree(flat)
+    tree = F.program_tree(flat)
     want = M.abstract_params(program_cfg("bfloat16"))
     assert jax.tree.structure(tree) == jax.tree.structure(want)
     for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(want)):
@@ -80,7 +79,7 @@ def test_train_data_is_the_programs_synthetic_stream():
 
 def test_loss_and_grads_match_the_program(M):
     w = R.stacked_weights(ARCH, SEED)
-    params = program_tree(w)
+    params = F.program_tree(w)
     tokens, targets = tokens_at(SEED, 0, 2, 32, 256)
     cfg = program_cfg()
     with jax.default_matmul_precision("highest"):
@@ -88,7 +87,7 @@ def test_loss_and_grads_match_the_program(M):
             lambda p: M.loss_fn(cfg, p, {"tokens": tokens, "targets": targets})[0])(params)
         rl, rg = jax.value_and_grad(lambda w: R.loss_fn(ARCH, w, tokens, targets))(w)
     np.testing.assert_allclose(float(pl), float(rl), rtol=1e-5)
-    got = {leaf_name(p): g for p, g in jax.tree_util.tree_flatten_with_path(pg)[0]}
+    got = F.reference_leaves(pg)
     for name, g in rg.items():
         np.testing.assert_allclose(np.asarray(got[name]), np.asarray(g), rtol=1e-3,
                                    atol=1e-5 * float(jnp.max(jnp.abs(g))), err_msg=name)
@@ -104,7 +103,7 @@ def _ref_logits(w, ids):
 def test_prefill_and_decode_through_the_cache_match(M):
     cfg = program_cfg()
     w = R.make_weights(ARCH, SEED, jnp.float32)
-    params = program_tree(w)
+    params = F.program_tree(w)
     ids = list(np.random.default_rng(0).integers(0, 256, 20))
     prompt, follow = ids[:14], ids[14:]
     with jax.default_matmul_precision("highest"):
@@ -131,7 +130,7 @@ def _served(M, cfg, params, prompt, n):
 def test_token_gap_rejects_the_fp8_control(M):
     """The served-token comparison at this size's limit: the bf16 program
     passes it, the reference in fp8 (the control) fails it."""
-    params = program_tree(R.make_weights(ARCH, SEED, jnp.bfloat16))
+    params = F.program_tree(R.make_weights(ARCH, SEED, jnp.bfloat16))
     cfg = program_cfg("bfloat16")
     rng = np.random.default_rng(5)
     seqs = []

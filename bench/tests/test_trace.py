@@ -41,6 +41,31 @@ def test_busy_ops_and_named_gaps():
     assert r["busy_s"] + sum(gaps.values()) == pytest.approx(6.0)
 
 
+def test_each_chip_busy_and_the_stage_handoffs():
+    """Each device's busy time, and the ops that pass activations between
+    pipeline stages (as the TPU's trace names them), with the two readers of
+    the four-chip cell over them."""
+    ops = {
+        "/device:TPU:0": [("fusion.1", 0.0, 4.0), ("collective-permute-start.1", 4.0, 4.5),
+                          ("collective-permute-done.1", 4.2, 5.0)],
+        "/device:TPU:1": [("fusion.1", 0.0, 2.0), ("collective-permute-done.1", 9.0, 10.0)],
+    }
+    r = reduce_events(ops, [], (0.0, 10.0))
+    assert r["busy_s_by_device"] == [5.0, 3.0]
+    assert r["busy_s"] == 4.0
+    assert r["handoff_s"] == pytest.approx((1.0 + 1.0) / 2)
+    out = harness.Outcome(end_to_end={}, checks=[], attempted=0, failed=0, memory_peak_bytes=0,
+                          layer={"kind": "train", "trace": r}, trace=r)
+    assert harness.metric_reader("stage_busy_spread.train").read(out) == pytest.approx(20.0)
+    assert harness.metric_reader("handoff_share.train").read(out) == pytest.approx(10.0)
+    # one chip: no spread; no hand-off op: nothing to read, not 0
+    one = reduce_events({"/device:TPU:0": [("fusion.1", 0.0, 4.0)]}, [], (0.0, 10.0))
+    assert one["handoff_s"] is None
+    out.layer["trace"] = one
+    assert harness.metric_reader("stage_busy_spread.train").read(out) is None
+    assert harness.metric_reader("handoff_share.train").read(out) is None
+
+
 def test_a_gap_no_span_covers_is_none():
     r = reduce_events({"d": [("op", 2.0, 3.0)]}, [], (0.0, 4.0))
     assert dict(r["idle_gaps"]) == {"none": 3.0}

@@ -8,7 +8,12 @@ run on the chip.  The host plane (``/host:CPU``) holds the benchmark's own
 marks the traced part of the window.
 
 * busy: the union of the op intervals of each device inside the window,
-  averaged over the devices the cell uses;
+  averaged over the devices the cell uses, and each device's own (in the
+  order of the devices' names);
+* hand-off: the union of the intervals of the ops that pass activations and
+  their gradients between pipeline stages (``collective-permute`` in the op's
+  name) inside the window, averaged over the devices; ``None`` where the
+  window holds none;
 * device ops: each op name's summed time inside the window, averaged over the
   devices, the ten largest;
 * idle gaps: each stretch of the window with no op on a device, split over
@@ -27,6 +32,7 @@ __all__ = ["reduce_dir", "reduce_file", "reduce_events", "union_length"]
 
 OPS_LINE = "XLA Ops"
 WINDOW_SPAN = "bench/traced_window"
+HANDOFF_OP = "collective-permute"
 
 
 def union_length(intervals: list[tuple[float, float]]) -> float:
@@ -78,10 +84,15 @@ def reduce_events(device_ops: dict[str, list[tuple[str, float, float]]],
     lo, hi = window
     spans = [(n, s, e) for n, s, e in host_spans if n != WINDOW_SPAN]
     busy, per_op, idle = 0.0, defaultdict(float), defaultdict(float)
+    busy_by_device, handoff, handoff_ops = [], 0.0, 0
     for d in devices:
         clipped = [(n, max(s, lo), min(e, hi)) for n, s, e in device_ops[d] if e > lo and s < hi]
         iv = [(s, e) for _, s, e in clipped]
-        busy += union_length(iv)
+        busy_by_device.append(union_length(iv))
+        busy += busy_by_device[-1]
+        passes = [(s, e) for n, s, e in clipped if HANDOFF_OP in n]
+        handoff += union_length(passes)
+        handoff_ops += len(passes)
         for n, s, e in clipped:
             per_op[n] += e - s
         for g in _gaps(iv, lo, hi):
@@ -90,6 +101,8 @@ def reduce_events(device_ops: dict[str, list[tuple[str, float, float]]],
     rank = lambda d: sorted(([n, v / k] for n, v in d.items()), key=lambda x: -x[1])[:top]  # noqa: E731
     return {
         "busy_s": busy / k,
+        "busy_s_by_device": busy_by_device,
+        "handoff_s": handoff / k if handoff_ops else None,
         "window_s": hi - lo,
         "device_ops": rank(per_op),
         "idle_gaps": rank(idle),
