@@ -125,7 +125,10 @@ def train_phase(cfg, seed: int) -> None:
     check(out["checkpoints"] == [steps], f"checkpoints on disk: {out['checkpoints']}")
     totals = [0.0] + [r["step_seconds_total"] for r in rows]
     step_s = [b - a for a, b in zip(totals, totals[1:])]
-    median = statistics.median(step_s[1:])  # the first step is the warm-up
+    # step k's row is logged once the loop has waited on it, in iteration
+    # k + 1 (the last at shutdown, with no step window of its own): the
+    # first difference holds the warm-up, the last is empty
+    median = statistics.median(step_s[1:-1])
     emit(
         phase="train", arch=cfg.name, n_layers=cfg.n_layers, seq_len=SEQ_LEN,
         global_batch=GLOBAL_BATCH, compile_s=out["compile_s"], step_s=step_s,

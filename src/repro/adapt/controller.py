@@ -17,8 +17,9 @@ time".  The measurement side (clocks, timers, cross-host reductions) lives in
 
 The loop is deliberately synchronous and schedulable: drive it from a Cactus
 bin via :meth:`repro.core.schedule.Scheduler.attach_control_loop` (the
-production path in ``repro.launch.train``) or call ``poll`` by hand in tests
-and simulations.  Controllers in this package: checkpoint admission
+production path in ``repro.launch.train``, where the poll of iteration n runs
+while step n is on the device and sees step n - 1's metrics) or call ``poll``
+by hand in tests and simulations.  Controllers in this package: checkpoint admission
 (:mod:`repro.adapt.checkpoint`, the paper's AdaptCheck generalized) and
 straggler response (:mod:`repro.adapt.stragglers`, rebalance/evict).
 """

@@ -143,6 +143,40 @@ def attention_layers_by_path(cfg: ArchConfig, seq_len: int, *, devices: int) -> 
     return (n if impl == "pallas" else 0, n if impl == "chunked" else 0)
 
 
+class StepMetric:
+    """One metric of a train step that may still be on the device.
+
+    Its copy to the host starts when the step is dispatched, and ``float()``
+    waits on this value alone.  Once the loop has waited on the step,
+    :meth:`fetch` keeps the host float and drops the device array, so a
+    metric held after its step is done holds no device memory.
+    """
+
+    __slots__ = ("_array", "_value")
+
+    def __init__(self, array: jax.Array) -> None:
+        array.copy_to_host_async()
+        self._array, self._value = array, None
+
+    def __float__(self) -> float:
+        if self._array is not None:
+            self._value, self._array = float(self._array), None
+        return self._value
+
+    @staticmethod
+    def fetch(metrics: dict[str, "StepMetric"]) -> dict[str, float]:
+        """Every metric of one step as host floats, in one ``device_get``."""
+        pending = {k: m._array for k, m in metrics.items() if m._array is not None}
+        for k, v in jax.device_get(pending).items():
+            metrics[k]._value, metrics[k]._array = float(v), None
+        return {k: float(m) for k, m in metrics.items()}
+
+    @staticmethod
+    def arrays(metrics: dict[str, "StepMetric"]) -> list[jax.Array]:
+        """The device arrays of ``metrics`` not yet fetched."""
+        return [m._array for m in metrics.values() if m._array is not None]
+
+
 def _bytes_by_device(tree) -> dict[int, int]:
     """Bytes of ``tree``'s device arrays held on each device id (replicas
     counted on every device that holds one; host leaves are not counted)."""
@@ -219,8 +253,8 @@ def run_training(
     ckpt_timer_name = "CHECKPOINT/adaptcheck::write"
     ckpt_write_scope = sess.scope_handle(ckpt_timer_name)
     # the train step's window in three parts: the host enqueuing the compiled
-    # step, the wait for the device to finish it, and the metrics' copy to
-    # the host
+    # step, the wait for the device to finish the step before it, and that
+    # step's metrics' copy to the host
     dispatch_scope = sess.scope_handle("EVOL/trainer::train_step/dispatch")
     wait_scope = sess.scope_handle("EVOL/trainer::train_step/wait")
     fetch_scope = sess.scope_handle("EVOL/trainer::train_step/fetch")
@@ -267,6 +301,7 @@ def run_training(
         contract — an eviction must never outrun its safety checkpoint)."""
         if manager is None:
             raise RuntimeError("no checkpoint manager bound")
+        finish_step(st)
         t0 = time.monotonic()
         with ckpt_write_scope:
             # labeled with the update count, not the adapt step: the barrier
@@ -332,6 +367,8 @@ def run_training(
     bump_flops = sess.counter("xla_flops", absolute=True)
     bump_tokens = sess.counter("tokens", absolute=True)
     bump_steps = sess.counter("steps", absolute=True)
+    # steps dispatched while their predecessor was still in flight
+    bump_overlapped = sess.counter("train_steps_overlapped")
     # which attention the step runs, counted once at startup (the MLP
     # pipeline has none; the pipeline's stages see one device's arrays)
     attn_flash, attn_chunked = (
@@ -507,18 +544,40 @@ def run_training(
     sch.schedule(fetch_data, bin="PRESTEP", thorn="data")
 
     # --- EVOL: the jitted step -----------------------------------------------------
-    def train_step(s: RunState) -> None:
-        with dispatch_scope:
-            params, opt_state, metrics = s["exec"](s["params"], s["opt_state"], s["batch"])
+    # One step stays queued on the device: train_step(i) dispatches step i and
+    # then waits on step i - 1, so the rest of iteration i (ANALYSIS,
+    # CHECKPOINT, OUTPUT, the next batch's PRESTEP) runs while step i
+    # executes.  After it, s["params"], s["opt_state"] and s["metrics"]
+    # (StepMetric values; float() blocks on that value alone) are step i's,
+    # and s["metrics_done"] holds (i - 1, host floats): what logs, status and
+    # controllers read.
+    def finish_step(s: RunState) -> None:
+        """Wait on the step in flight, if any, and publish its metrics as
+        host floats in ``s["metrics_done"]``."""
+        if s.get("in_flight") is None:
+            return
+        step, metrics = s["in_flight"]
         with wait_scope:
-            metrics = jax.block_until_ready(metrics)
-        s["params"], s["opt_state"] = params, opt_state
-        s["updates"] = s.iteration + 1
+            jax.block_until_ready(StepMetric.arrays(metrics))
         with fetch_scope:
-            s["metrics"] = {k: float(v) for k, v in metrics.items()}
+            s["metrics_done"] = (step, StepMetric.fetch(metrics))
+        s["in_flight"] = None
         bump_flops(model_flops)
         bump_tokens(float(s["built"].tokens_per_call))
         bump_steps(1.0)
+
+    def train_step(s: RunState) -> None:
+        # inputs are read from s at dispatch: a restore, reload or steering
+        # routine that replaced them since the last step takes effect here
+        with dispatch_scope:
+            params, opt_state, metrics = s["exec"](s["params"], s["opt_state"], s["batch"])
+            metrics = {k: StepMetric(v) for k, v in metrics.items()}
+        s["params"], s["opt_state"], s["metrics"] = params, opt_state, metrics
+        s["updates"] = s.iteration + 1
+        if s.get("in_flight") is not None:
+            bump_overlapped(1.0)
+        finish_step(s)
+        s["in_flight"] = (s.iteration, metrics)
 
     sch.schedule(train_step, bin="EVOL", thorn="trainer")
 
@@ -537,6 +596,9 @@ def run_training(
         s["last_ckpt_decision"] = decision
         if decision is None or not decision.checkpoint:
             return
+        # the save blocks on the step in flight anyway: wait on it first, so
+        # the write's measured cost is the write's alone
+        finish_step(s)
         with ckpt_write_scope:
             stats = manager.save(
                 s["updates"],
@@ -548,24 +610,36 @@ def run_training(
     sch.schedule(adaptive_checkpoint, bin="CHECKPOINT", thorn="adaptcheck")
 
     # --- OUTPUT ------------------------------------------------------------------------
-    def output(s: RunState) -> None:
+    def log_done(s: RunState) -> None:
+        """Log the newest completed step's metrics once, under its own step."""
+        done = s.get("metrics_done")
+        if done is None or done[0] == s.get("logged_step"):
+            return
+        step, m = done
+        s["logged_step"] = step
         if logger is not None:
-            logger.log(s.iteration, extra=s.get("metrics"))
+            logger.log(step, extra=m)
         if status is not None:
-            status.write({"iteration": s.iteration, **(s.get("metrics") or {})})
+            status.write({"iteration": step, **m})
+
+    def output(s: RunState) -> None:
+        log_done(s)
         if settings.report_every and s.iteration % settings.report_every == 0:
             if settings.metrics_textfile:
                 exporter.write_textfile(settings.metrics_textfile)
-            m = s.get("metrics") or {}
-            print(
-                f"[train] step {s.iteration:5d} loss={m.get('loss', float('nan')):.4f} "
-                f"ce={m.get('ce', float('nan')):.4f} gnorm={m.get('grad_norm', 0):.2f}"
-            )
+            if s.get("metrics_done") is not None:
+                step, m = s["metrics_done"]
+                print(
+                    f"[train] step {step:5d} loss={m.get('loss', float('nan')):.4f} "
+                    f"ce={m.get('ce', float('nan')):.4f} gnorm={m.get('grad_norm', 0):.2f}"
+                )
 
     sch.schedule(output, bin="OUTPUT", thorn="report")
 
     # --- SHUTDOWN --------------------------------------------------------------------
     def shutdown(s: RunState) -> None:
+        finish_step(s)
+        log_done(s)
         if manager is not None and settings.ckpt_mode != "off":
             with ckpt_write_scope:
                 manager.save(
@@ -592,7 +666,7 @@ def run_training(
 
     summary = {
         "iterations": st.iteration,
-        "final_metrics": st.get("metrics"),
+        "final_metrics": (st.get("metrics_done") or (None, None))[1],
         "total_seconds": db.get("simulation/total").seconds(),
         "bin_seconds": bin_distribution(db),
         "checkpoint": controller.summary() if controller else {},

@@ -191,17 +191,23 @@ def test_training_step_parts_under_a_trace(tmp_path):
     steps = sorted(rec.spans("EVOL/trainer::train_step"), key=lambda e: e["start"])
     assert len(steps) == 2
     assert all(s["stats"]["parent"] == "bin/EVOL" for s in steps)
-    for part in ("dispatch", "wait", "fetch"):
+    (shutdown,) = rec.spans("SHUTDOWN/driver::shutdown")
+    # each step window dispatches its own step and waits on the one before:
+    # step 0 waits on nothing, step 1 on step 0, and the shutdown drains step 1
+    for part, owners in (("dispatch", steps), ("wait", [steps[1], shutdown]),
+                         ("fetch", [steps[1], shutdown])):
         windows = sorted(rec.spans(f"EVOL/trainer::train_step/{part}"),
                          key=lambda e: e["start"])
         assert len(windows) == 2, part
-        for w, s in zip(windows, steps):
-            assert w["stats"]["parent"] == "EVOL/trainer::train_step"
+        for w, s in zip(windows, owners):
+            assert w["stats"]["parent"] == s["name"]
             assert _inside(w, s)
-    # the xla_flops counter bumped inside the step's window: the step's count
+    # the xla_flops counter is bumped where a step's completion is seen: the
+    # step window holds step 0's count, the shutdown step 1's
     cfg = get_smoke_config("llama3.2-1b")
-    flops = sess.db.get("EVOL/trainer::train_step").read_flat()["xla_flops"]
-    assert flops == pytest.approx(2 * _flops_per_step(cfg, 32, 2))
+    for timer in ("EVOL/trainer::train_step", "SHUTDOWN/driver::shutdown"):
+        flops = sess.db.get(timer).read_flat()["xla_flops"]
+        assert flops == pytest.approx(_flops_per_step(cfg, 32, 2)), timer
 
 
 def test_flops_per_step_at_glm4_9b_train():
