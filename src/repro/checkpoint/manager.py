@@ -5,8 +5,7 @@ phase — device→host snapshot + submission, the part that steals wall time fr
 compute and is what AdaptCheck bounds — and an *async* phase on a writer
 thread.  The blocking seconds and written bytes are reported to the caller and
 pushed onto the ``io`` counter channels so every timer window can see I/O
-traffic.  ``synchronous=True`` reproduces the paper's blocking checkpointing
-(used as the paper-faithful baseline in benchmarks).
+traffic.  ``synchronous=True`` reproduces the paper's blocking checkpointing.
 
 Fault tolerance is structural, not best-effort:
 

@@ -2,7 +2,7 @@
 InternViT + InternLM2 backbone.  [arXiv:2404.16821; hf]
 
 Backbone only, per the assignment: the vision frontend is a STUB —
-``input_specs()`` provides 256 precomputed patch embeddings (B, 256, d_model)
+the batch carries 256 precomputed patch embeddings (B, 256, d_model)
 prepended to the text sequence; seq_len counts the combined sequence.
 vocab 92553 padded to 92672 for TP-16 divisibility.
 """

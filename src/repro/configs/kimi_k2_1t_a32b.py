@@ -4,9 +4,8 @@ vocab=163840, MoE 384e top-8.  Trillion-parameter MoE (paper-table entry).
 
 Notes: head_dim pinned to 128 (64·128 ≠ d_model; q/o projections are
 rectangular, standard for K2-class models).  ``d_ff`` is per-expert width.
-Sharding: TP+FSDP+EP — at 1T parameters even the 512-chip multi-pod mesh
-cannot hold AdamW train state (see EXPERIMENTS.md §Dry-run for honest
-bytes-per-device numbers); the dry-run proves the sharding is coherent.
+Sharding: TP+FSDP+EP.  At 1T parameters the AdamW train state (16 B a
+parameter) needs about 16 TB, more than 512 v5e chips hold.
 """
 
 from ..models.config import ArchConfig, MoESettings

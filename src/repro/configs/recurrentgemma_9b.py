@@ -2,9 +2,8 @@
 d_ff=12288 vocab=256000 — RG-LRU + local attention, 1 recurrent : 2 local-attn
 pattern per the assignment, window 2048.  [arXiv:2402.19427; unverified]
 
-``long_500k`` runs for this arch: the RG-LRU state is O(d) and the local
-attention cache is a 2048-slot ring buffer, so the 524,288-token decode cell is
-sub-quadratic (DESIGN.md §4).
+Decode is sub-quadratic in context length: the RG-LRU state is O(d) and the
+local attention cache is a 2048-slot ring buffer.
 """
 
 from ..models.config import ArchConfig

@@ -1,8 +1,7 @@
 """rwkv6-1.6b [ssm] — 24L d_model=2048 (attention-free) d_ff=7168 vocab=65536.
 Finch: data-dependent decay WKV recurrence.  [arXiv:2404.05892; unverified]
 
-``long_500k`` runs for this arch: decode state is O(H·K·V) per layer,
-independent of context length.
+Decode state is O(H·K·V) per layer, independent of context length.
 """
 
 from ..models.config import ArchConfig

@@ -2,7 +2,7 @@
 d_ff=8192 vocab=256206.  [arXiv:2308.11596; hf]
 
 Backbone only, per the assignment: the speech frontend is a STUB —
-``input_specs()`` provides precomputed frame embeddings (B, S, d_model).
+the batch carries precomputed frame embeddings (B, S, d_model).
 24 encoder layers + 24 decoder layers (speech encoder + text decoder).
 vocab 256206 is padded to 256256 for TP-16 divisibility (loss masks the pad).
 """

@@ -8,7 +8,7 @@ a standard registration mechanism so new metrics require *no modification to any
 existing timing code*: every :class:`~repro.core.timers.Timer` automatically
 encapsulates one instance of every registered clock.
 
-Hardware adaptation (see DESIGN.md): TPUs expose no user-readable PMU, so the
+Hardware adaptation: TPUs expose no user-readable PMU, so the
 PAPI-analogue clocks here are *derived* device clocks (``xla_flops``/``xla_bytes``)
 fed by XLA's compiled cost analysis, plus generic :class:`CounterClock` channels
 for framework events (checkpoint bytes, collective bytes, tokens processed).

@@ -1,7 +1,7 @@
 """Deterministic synthetic LM data.
 
 Modes:
-  * ``random`` — uniform tokens (throughput/dry-run benchmarking).
+  * ``random`` — uniform tokens (throughput benchmarking).
   * ``copy``   — first half random, second half repeats the first half with
     next-token targets; a learnable induction task (examples/train_llm.py).
   * ``skewed`` — Zipf-distributed tokens; the unigram statistics are learnable

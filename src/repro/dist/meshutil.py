@@ -2,12 +2,10 @@
 
 ``local_mesh`` builds a device mesh from a settings-style ``mesh_shape`` over
 whatever devices this process has — one CPU in unit tests, eight forced host
-devices in the mini dry-run, real accelerators in production — with clear
+devices in the multi-device tests, real accelerators in production — with clear
 errors when the requested shape cannot be satisfied.  ``remove_host`` is the
 eviction rebuild: the same mesh minus one slice along an axis, used by the
 straggler-response controller when a host is pulled from the fleet.
-Production pod topologies live in :mod:`repro.launch.mesh`; this module is the
-everything-else path.
 """
 
 from __future__ import annotations
@@ -61,7 +59,7 @@ def local_mesh(
         raise ValueError(
             f"mesh_shape {shape} needs {n_needed} devices but only "
             f"{len(devices)} are visible; set XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={n_needed} for CPU dry-runs"
+            f"--xla_force_host_platform_device_count={n_needed} for CPU runs"
         )
     return jax.make_mesh(shape, names, devices=devices[:n_needed],
                          axis_types=(AxisType.Auto,) * len(names))

@@ -5,7 +5,7 @@ a mesh axis and streams microbatches through them: at tick ``t`` stage 0
 ingests microbatch ``t`` while every other stage works on the activation its
 predecessor shipped via ``ppermute`` at tick ``t-1``.  After
 ``n_micro + n_stages - 1`` ticks the last stage has emitted every microbatch.
-It is the forward-only schedule (serving / dry-run measurement path); the
+It is the forward-only schedule (no gradients); the
 bubble fraction is ``(n_stages - 1) / (n_micro + n_stages - 1)``, so more
 microbatches amortize the fill/drain cost exactly as in the GPipe paper.
 

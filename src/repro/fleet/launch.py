@@ -143,8 +143,8 @@ def run_fleet(settings: FleetSettings) -> dict[str, Any]:
         transport=transport,
     )
 
-    # -- measured re-shard cost: baseline seed + live save/restore ----------
-    cost = ReshardCost.from_baseline()
+    # -- measured re-shard cost: default seed + live save/restore -----------
+    cost = ReshardCost()
     cost.rebuild_s += settings.extra_reshard_cost_s
     manager = CheckpointManager(
         os.path.join(root, "ckpt"), keep_n=3, synchronous=True, fsync=False

@@ -6,11 +6,10 @@ first post-change step runs.  The Cactus Worm experiments and AdaptCheck both
 frame this the same way: move work only when the projected win over a payback
 horizon exceeds the cost of moving it.
 
-:class:`ReshardCost` carries the cost side in seconds — seeded from the
-committed checkpoint benchmark baselines (``benchmarks/baselines/
-checkpoint.json``: measured ``save_sync`` + ``restore_latest`` per-call times)
-and updated with live-measured save/restore seconds as the run observes its
-own checkpoints (EWMA, so a run on slower disks converges to its own truth).
+:class:`ReshardCost` carries the cost side in seconds — starting from
+conservative defaults and updated with live-measured save/restore seconds as
+the run observes its own checkpoints (EWMA, so a run on slower disks converges
+to its own truth).
 
 :class:`PaybackPolicy` turns that into the two gates the control plane calls:
 
@@ -28,8 +27,6 @@ the move was skipped — every skip is logged, none is silent.
 
 from __future__ import annotations
 
-import json
-import os
 from dataclasses import dataclass
 
 from ..adapt.controller import ControlAction
@@ -37,28 +34,18 @@ from ..dist.stragglers import StragglerReport
 
 __all__ = ["PaybackPolicy", "ReshardCost"]
 
-#: fallback seconds when no baseline file is reachable (the committed
-#: tiny-scale CPU numbers, rounded up — a conservative floor, not a model)
-_FALLBACK_SAVE_S = 0.006
-_FALLBACK_RESTORE_S = 0.003
-
-_BASELINE_ROWS = {"ckpt/save_sync": "save_s", "ckpt/restore_latest": "restore_s"}
-
-
-def _default_baseline_path() -> str:
-    # repo layout: src/repro/fleet/payback.py -> benchmarks/baselines/...
-    here = os.path.dirname(os.path.abspath(__file__))
-    return os.path.join(
-        here, "..", "..", "..", "benchmarks", "baselines", "checkpoint.json"
-    )
+#: starting seconds before the run has observed a save or restore: a tiny
+#: synchronous checkpoint on a CPU host, rounded up — a floor, not a model
+_DEFAULT_SAVE_S = 0.006
+_DEFAULT_RESTORE_S = 0.003
 
 
 @dataclass
 class ReshardCost:
     """Seconds one membership change costs the fleet, by phase."""
 
-    save_s: float = _FALLBACK_SAVE_S
-    restore_s: float = _FALLBACK_RESTORE_S
+    save_s: float = _DEFAULT_SAVE_S
+    restore_s: float = _DEFAULT_RESTORE_S
     #: plan re-apportionment + mesh rebuild (usually dwarfed by the I/O)
     rebuild_s: float = 0.0
     #: EWMA weight for live observations folded in via :meth:`observe`
@@ -66,23 +53,6 @@ class ReshardCost:
 
     def total(self) -> float:
         return self.save_s + self.restore_s + self.rebuild_s
-
-    @classmethod
-    def from_baseline(cls, path: str | None = None) -> ReshardCost:
-        """Seed from the measured checkpoint benchmark baselines (µs/call
-        rows); falls back to the conservative defaults when unreadable."""
-        path = path or _default_baseline_path()
-        kwargs: dict[str, float] = {}
-        try:
-            with open(path, encoding="utf-8") as f:
-                payload = json.load(f)
-            for row in payload.get("rows", ()):
-                field = _BASELINE_ROWS.get(row.get("name"))
-                if field is not None:
-                    kwargs[field] = float(row["us_per_call"]) * 1e-6
-        except (OSError, ValueError, KeyError, TypeError):
-            pass
-        return cls(**kwargs)
 
     def observe(
         self, save_s: float | None = None, restore_s: float | None = None

@@ -1,6 +1,6 @@
 """Flash attention TPU kernels (Pallas): forward + backward (dq, dkv).
 
-TPU adaptation (DESIGN.md §6): the online-softmax accumulators (m, l, acc)
+TPU adaptation: the online-softmax accumulators (m, l, acc)
 live in VMEM scratch that persists across the *sequential* last grid dimension
 (``arbitrary`` semantics) — the TPU analogue of FlashAttention's SRAM-resident
 per-CTA accumulators.  Block shapes are (block_q|k, head_dim) with

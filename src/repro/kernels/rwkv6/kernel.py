@@ -1,6 +1,6 @@
 """RWKV-6 WKV Pallas TPU kernel.
 
-TPU adaptation (DESIGN.md §6): GPU RWKV kernels keep the per-head (K,V) state
+TPU adaptation: GPU RWKV kernels keep the per-head (K,V) state
 in registers/shared memory of one CTA and scan tokens sequentially.  On TPU we
 keep the state in **VMEM scratch** that persists across the sequential chunk
 dimension of the grid: grid = (B·H, n_chunks) with semantics

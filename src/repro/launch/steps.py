@@ -1,11 +1,8 @@
-"""Step builders: jitted train / prefill / serve steps with full sharding info.
+"""Step builders: the jitted train step, and the 1F1B pipeline train steps.
 
-``input_specs(cfg, shape)`` returns weak-type-correct ShapeDtypeStruct stand-ins
-for every model input of a shape cell — shardable, no device allocation — used
-by both the dry-run (lower+compile only) and the launchers (shapes for real
-allocation).  ``decode_*``/``long_*`` cells lower ``serve_step`` (one new token
-against a seq_len KV cache); ``prefill_*`` lowers the prefill; ``train_*``
-lowers ``train_step``.
+Each builder returns a :class:`BuiltStep` holding the step, its shardings and
+the abstract shapes of its state and inputs, so a launcher can place real
+arrays (or lower the step ahead of time) without building them first.
 """
 
 from __future__ import annotations
@@ -28,14 +25,9 @@ from ..timing import timed
 
 __all__ = [
     "rules_for",
-    "input_specs",
-    "batch_axes",
     "make_train_step",
     "make_pipeline_train_step",
     "make_transformer_pipeline_train_step",
-    "make_prefill_step",
-    "make_serve_step",
-    "shardings_for",
 ]
 
 
@@ -46,89 +38,34 @@ def rules_for(cfg: ArchConfig, overrides: dict[str, Any] | None = None) -> Shard
     return rules
 
 
-def _bf16():
-    return jnp.bfloat16
-
-
 # ---------------------------------------------------------------------------
-# Input specs per shape cell
+# Train inputs
 # ---------------------------------------------------------------------------
 
-def batch_axes(cfg: ArchConfig, kind: str) -> dict[str, tuple]:
-    """Logical axes of each batch input."""
-    axes: dict[str, tuple] = {}
-    if kind in ("train",):
-        axes["tokens"] = ("batch", "seq")
-        axes["targets"] = ("batch", "seq")
-    elif kind == "prefill":
-        axes["tokens"] = ("batch", "seq")
-    if cfg.family == "vlm" and kind in ("train", "prefill"):
-        axes["patch_embeds"] = ("batch", None, None)
-    if cfg.family == "encdec" and kind in ("train", "prefill"):
-        axes["src_frames"] = ("batch", "seq", None)
-    return axes
-
-
-def input_specs(cfg: ArchConfig, shape: ShapeConfig) -> dict[str, Any]:
-    """ShapeDtypeStruct stand-ins for the step inputs of this cell."""
+def _train_batch(cfg: ArchConfig, shape: ShapeConfig, mesh: Mesh, rules: ShardingRules):
+    """ShapeDtypeStruct stand-ins for the train batch of ``shape``, and their
+    shardings."""
     b, s = shape.global_batch, shape.seq_len
-    i32 = jnp.int32
-    if shape.kind == "train":
-        text = s - cfg.n_vision_patches if cfg.family == "vlm" else s
-        out: dict[str, Any] = {
-            "tokens": jax.ShapeDtypeStruct((b, text), i32),
-            "targets": jax.ShapeDtypeStruct((b, text), i32),
-        }
-        if cfg.family == "vlm":
-            out["patch_embeds"] = jax.ShapeDtypeStruct(
-                (b, cfg.n_vision_patches, cfg.d_model), _bf16()
-            )
-        if cfg.family == "encdec":
-            out["src_frames"] = jax.ShapeDtypeStruct((b, s, cfg.d_model), _bf16())
-        return out
-    if shape.kind == "prefill":
-        text = s - cfg.n_vision_patches if cfg.family == "vlm" else s
-        if cfg.family == "encdec":
-            # encode seq_len source frames; prefill the decoder with BOS
-            return {
-                "tokens": jax.ShapeDtypeStruct((b, 1), i32),
-                "src_frames": jax.ShapeDtypeStruct((b, s, cfg.d_model), _bf16()),
-            }
-        out = {"tokens": jax.ShapeDtypeStruct((b, text), i32)}
-        if cfg.family == "vlm":
-            out["patch_embeds"] = jax.ShapeDtypeStruct(
-                (b, cfg.n_vision_patches, cfg.d_model), _bf16()
-            )
-        return out
-    if shape.kind == "decode":
-        return {
-            "tokens": jax.ShapeDtypeStruct((b, 1), i32),
-            "cache": M.abstract_cache(cfg, b, s),
-        }
-    raise ValueError(f"unknown shape kind {shape.kind!r}")
-
-
-# ---------------------------------------------------------------------------
-# Sharding resolution
-# ---------------------------------------------------------------------------
-
-def shardings_for(axes_tree_, abstract_tree, mesh: Mesh, rules: ShardingRules):
-    return tree_shardings(axes_tree_, abstract_tree, mesh, rules)
-
-
-def _batch_shardings(cfg, shape, mesh, rules):
-    specs = input_specs(cfg, shape)
-    axes = batch_axes(cfg, shape.kind)
-    out = {}
-    for name, sds in specs.items():
-        if name == "cache":
-            out[name] = tree_shardings(
-                M.cache_axes(cfg, shape.global_batch, shape.seq_len), sds, mesh, rules
-            )
-        else:
-            ax = axes.get(name, ("batch",) + (None,) * (len(sds.shape) - 1))
-            out[name] = NamedSharding(mesh, spec_for(ax, sds.shape, mesh, rules))
-    return out
+    text = s - cfg.n_vision_patches if cfg.family == "vlm" else s
+    inputs = {
+        "tokens": (jax.ShapeDtypeStruct((b, text), jnp.int32), ("batch", "seq")),
+        "targets": (jax.ShapeDtypeStruct((b, text), jnp.int32), ("batch", "seq")),
+    }
+    if cfg.family == "vlm":
+        inputs["patch_embeds"] = (
+            jax.ShapeDtypeStruct((b, cfg.n_vision_patches, cfg.d_model), jnp.bfloat16),
+            ("batch", None, None),
+        )
+    if cfg.family == "encdec":
+        inputs["src_frames"] = (
+            jax.ShapeDtypeStruct((b, s, cfg.d_model), jnp.bfloat16), ("batch", "seq", None)
+        )
+    specs = {name: sds for name, (sds, _) in inputs.items()}
+    shardings = {
+        name: NamedSharding(mesh, spec_for(axes, sds.shape, mesh, rules))
+        for name, (sds, axes) in inputs.items()
+    }
+    return specs, shardings
 
 
 # ---------------------------------------------------------------------------
@@ -148,7 +85,7 @@ class BuiltStep:
 
 
 # scope-aware decorator: nests under the caller's active scope (the
-# STARTUP driver routine in launchers; bare in dry-runs)
+# STARTUP driver routine in launchers)
 @timed("steps::make_train_step")
 def make_train_step(
     cfg: ArchConfig,
@@ -159,13 +96,11 @@ def make_train_step(
     peak_lr: float = 3e-4,
     warmup_steps: int = 100,
     total_steps: int = 10000,
-    donate: bool = True,
-    accum_steps: int = 1,
 ) -> BuiltStep:
-    """``accum_steps > 1``: microbatched gradient accumulation — the global
-    batch is split into microbatches scanned sequentially; activation memory
-    scales down by the factor while FLOPs/collectives per token are unchanged
-    (§Perf H3)."""
+    """Build the jitted train step: loss, gradients and AdamW in one program.
+
+    Params and optimizer state are donated: the step updates them in place.
+    """
     p_axes = M.param_axes(cfg)
     p_abs = M.abstract_params(cfg)
     opt_cfg = opt_cfg if opt_cfg is not None else AdamWConfig()
@@ -174,12 +109,7 @@ def make_train_step(
 
     p_shard = tree_shardings(p_axes, p_abs, mesh, rules)
     o_shard = tree_shardings(o_axes, o_abs, mesh, rules)
-    b_shard = _batch_shardings(cfg, shape, mesh, rules)
-
-    def _grads(params, batch):
-        grad_fn = jax.value_and_grad(lambda p: M.loss_fn(cfg, p, batch), has_aux=True)
-        (loss, metrics), grads = grad_fn(params)
-        return grads, metrics
+    b_abs, b_shard = _train_batch(cfg, shape, mesh, rules)
 
     def train_step(params, opt_state, batch):
         with use_sharding(mesh, rules):
@@ -187,26 +117,8 @@ def make_train_step(
                 opt_state["step"], peak_lr=peak_lr, warmup_steps=warmup_steps,
                 total_steps=total_steps,
             )
-            if accum_steps > 1:
-                micro = jax.tree.map(
-                    lambda x: x.reshape(accum_steps, x.shape[0] // accum_steps, *x.shape[1:]),
-                    batch,
-                )
-
-                def body(acc, mb):
-                    g, m = _grads(params, mb)
-                    acc = jax.tree.map(
-                        lambda a, gg: a + gg.astype(jnp.float32) / accum_steps, acc, g
-                    )
-                    return acc, m
-
-                zero = jax.tree.map(
-                    lambda p: jnp.zeros(p.shape, jnp.float32), params
-                )
-                grads, metrics_seq = jax.lax.scan(body, zero, micro)
-                metrics = jax.tree.map(lambda m: jnp.mean(m, axis=0), metrics_seq)
-            else:
-                grads, metrics = _grads(params, batch)
+            grad_fn = jax.value_and_grad(lambda p: M.loss_fn(cfg, p, batch), has_aux=True)
+            (_, metrics), grads = grad_fn(params)
             params, opt_state, stats = adamw_update(opt_cfg, params, grads, opt_state, lr)
             metrics = dict(metrics)
             metrics.update(stats)
@@ -217,11 +129,11 @@ def make_train_step(
         train_step,
         in_shardings=(p_shard, o_shard, b_shard),
         out_shardings=(p_shard, o_shard, None),
-        donate_argnums=(0, 1) if donate else (),
+        donate_argnums=(0, 1),
     )
     return BuiltStep(
         fn=jitted,
-        abstract_inputs=(input_specs(cfg, shape),),
+        abstract_inputs=(b_abs,),
         in_shardings=(p_shard, o_shard, b_shard),
         out_shardings=(p_shard, o_shard, None),
         abstract_state={"params": p_abs, "opt_state": o_abs},
@@ -447,72 +359,3 @@ def make_transformer_pipeline_train_step(
         pipeline=pipeline,
         init_params=init_params,
     )
-
-
-@timed("steps::make_prefill_step")
-def make_prefill_step(cfg: ArchConfig, mesh: Mesh, rules: ShardingRules, shape: ShapeConfig) -> BuiltStep:
-    p_axes = M.param_axes(cfg)
-    p_abs = M.abstract_params(cfg)
-    p_shard = tree_shardings(p_axes, p_abs, mesh, rules)
-    b, s = shape.global_batch, shape.seq_len
-    c_abs = M.abstract_cache(cfg, b, s)
-    c_shard = tree_shardings(M.cache_axes(cfg, b, s), c_abs, mesh, rules)
-    b_shard = _batch_shardings(cfg, shape, mesh, rules)
-
-    def prefill_step(params, batch, cache):
-        with use_sharding(mesh, rules):
-            return M.prefill(cfg, params, batch, cache)
-
-    jitted = jax.jit(
-        prefill_step,
-        in_shardings=(p_shard, b_shard, c_shard),
-        out_shardings=(c_shard, None),
-        donate_argnums=(2,),
-    )
-    return BuiltStep(
-        fn=jitted,
-        abstract_inputs=(input_specs(cfg, shape), c_abs),
-        in_shardings=(p_shard, b_shard, c_shard),
-        out_shardings=(c_shard, None),
-        abstract_state={"params": p_abs},
-        tokens_per_call=shape.global_batch * shape.seq_len,
-    )
-
-
-@timed("steps::make_serve_step")
-def make_serve_step(cfg: ArchConfig, mesh: Mesh, rules: ShardingRules, shape: ShapeConfig) -> BuiltStep:
-    p_axes = M.param_axes(cfg)
-    p_abs = M.abstract_params(cfg)
-    p_shard = tree_shardings(p_axes, p_abs, mesh, rules)
-    b, s = shape.global_batch, shape.seq_len
-    c_abs = M.abstract_cache(cfg, b, s)
-    c_shard = tree_shardings(M.cache_axes(cfg, b, s), c_abs, mesh, rules)
-    tok_abs = jax.ShapeDtypeStruct((b, 1), jnp.int32)
-    tok_shard = NamedSharding(mesh, spec_for(("batch", None), (b, 1), mesh, rules))
-
-    def serve_step(params, cache, tokens):
-        with use_sharding(mesh, rules):
-            return M.decode_step(cfg, params, cache, tokens)
-
-    jitted = jax.jit(
-        serve_step,
-        in_shardings=(p_shard, c_shard, tok_shard),
-        out_shardings=(c_shard, None),
-        donate_argnums=(1,),
-    )
-    return BuiltStep(
-        fn=jitted,
-        abstract_inputs=(c_abs, tok_abs),
-        in_shardings=(p_shard, c_shard, tok_shard),
-        out_shardings=(c_shard, None),
-        abstract_state={"params": p_abs},
-        tokens_per_call=shape.global_batch,  # one new token per sequence
-    )
-
-
-def build_step(cfg: ArchConfig, mesh: Mesh, rules: ShardingRules, shape: ShapeConfig, **kw) -> BuiltStep:
-    if shape.kind == "train":
-        return make_train_step(cfg, mesh, rules, shape, **kw)
-    if shape.kind == "prefill":
-        return make_prefill_step(cfg, mesh, rules, shape)
-    return make_serve_step(cfg, mesh, rules, shape)

@@ -1,6 +1,6 @@
 
 from . import model
-from .config import SHAPES, ArchConfig, MoESettings, ShapeConfig
+from .config import ArchConfig, MoESettings, ShapeConfig
 
 
-__all__ = ["ArchConfig", "MoESettings", "ShapeConfig", "SHAPES", "model"]
+__all__ = ["ArchConfig", "MoESettings", "ShapeConfig", "model"]

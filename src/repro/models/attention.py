@@ -12,7 +12,7 @@ and the default that chooses between two of them.
   leaves the chip's fast memory.  Raises on shapes it cannot tile.
 * ``auto``    — the default: ``pallas`` where :func:`select_impl` finds a TPU,
   S and T that tile by 128 and one device's arrays, ``chunked`` elsewhere (so
-  every CPU run, tests and the dry-run included, takes ``chunked``).
+  every CPU run, tests included, takes ``chunked``).
 
 Shapes: q (B, S, H, hd); k/v (B, T, KV, hd); GQA group = H // KV.
 """

@@ -8,10 +8,10 @@ Every block exposes three pieces:
       "prefill" : full sequence, returns a filled cache,
       "decode"  : one token against the cache (S == 1).
 
-Cache design (DESIGN.md §3): global attention keeps (B, Smax, KV, hd) K/V
-written at absolute positions; sliding-window attention keeps a **ring buffer**
-of ``window`` slots plus per-slot absolute positions (this is what makes
-``long_500k`` sub-quadratic for the hybrid arch); RG-LRU keeps the (B, D) f32
+Cache design: global attention keeps (B, Smax, KV, hd) K/V written at
+absolute positions; sliding-window attention keeps a **ring buffer** of
+``window`` slots plus per-slot absolute positions (this is what keeps the
+hybrid arch's long-context decode sub-quadratic); RG-LRU keeps the (B, D) f32
 recurrence state and the (B, cw-1, D) conv tail; RWKV keeps the (B, H, K, V)
 f32 WKV state and the two token-shift vectors.
 """

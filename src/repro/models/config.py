@@ -2,10 +2,8 @@
 
 Each assigned architecture gets a ``src/repro/configs/<id>.py`` exporting
 ``CONFIG`` (the exact published shape) and ``SMOKE_CONFIG`` (a reduced config of
-the same family for CPU tests).  ``ShapeConfig`` encodes the assigned input
-shapes; ``train_*`` shapes lower ``train_step``, ``prefill_*`` lower the prefill
-step, and ``decode_*``/``long_*`` lower ``serve_step`` (one new token against a
-KV cache of ``seq_len``).
+the same family for CPU tests).  ``ShapeConfig`` is the sequence length and
+global batch a train step is built for.
 """
 
 from __future__ import annotations
@@ -13,7 +11,7 @@ from __future__ import annotations
 import dataclasses
 from dataclasses import dataclass
 
-__all__ = ["MoESettings", "ArchConfig", "ShapeConfig", "SHAPES"]
+__all__ = ["MoESettings", "ArchConfig", "ShapeConfig"]
 
 
 @dataclass(frozen=True)
@@ -73,14 +71,6 @@ class ArchConfig:
     norm_impl: str = "ref"
     #: pad vocab up to a multiple of this for sharding (logits masked to true vocab)
     vocab_pad_to: int = 256
-    #: §Perf knobs (EXPERIMENTS.md): pre-reshard embedding/lm_head before the
-    #: token gather (fixes FSDP involuntary remat)
-    embed_gather_constraint: bool = False
-    #: MoE dispatch activation constraints: "embed" (baseline; constrains the
-    #: hidden dim, conflicts with FSDP) | "tokens" (batch+expert only)
-    moe_dispatch_mode: str = "embed"
-    #: chunked cross-entropy: compute logits+CE in seq chunks of this size
-    loss_chunk: int = 0
     #: source/published reference for the config
     source: str = ""
 
@@ -146,32 +136,7 @@ class ArchConfig:
 @dataclass(frozen=True)
 class ShapeConfig:
     name: str
-    kind: str  # "train" | "prefill" | "decode"
+    kind: str  # "train"
     seq_len: int
     global_batch: int
 
-    @property
-    def tokens_per_step(self) -> int:
-        if self.kind == "decode":
-            return self.global_batch  # one new token per sequence
-        return self.global_batch * self.seq_len
-
-
-SHAPES: dict[str, ShapeConfig] = {
-    "train_4k": ShapeConfig("train_4k", "train", 4096, 256),
-    "prefill_32k": ShapeConfig("prefill_32k", "prefill", 32768, 32),
-    "decode_32k": ShapeConfig("decode_32k", "decode", 32768, 128),
-    "long_500k": ShapeConfig("long_500k", "decode", 524288, 1),
-}
-
-
-def shape_applicable(cfg: ArchConfig, shape: ShapeConfig) -> tuple[bool, str]:
-    """Whether a shape cell runs for an arch (DESIGN.md §4 records the skips)."""
-    if shape.name == "long_500k":
-        kinds = set(cfg.layer_kinds())
-        sub_quadratic = kinds <= {"rglru", "attn_local", "rwkv"} and (
-            "rglru" in kinds or "rwkv" in kinds
-        )
-        if not sub_quadratic:
-            return False, "pure full-attention arch: 500k decode is not sub-quadratic"
-    return True, ""

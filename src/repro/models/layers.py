@@ -4,8 +4,8 @@ Parameter handling convention
 -----------------------------
 Model code describes parameters with :class:`PSpec` trees (shape + logical axes
 + initializer).  ``materialize`` turns a spec tree into real arrays;
-``abstract`` turns it into ``jax.ShapeDtypeStruct``s (used by the dry-run so
-trillion-parameter configs never allocate); ``axes_tree`` extracts the logical
+``abstract`` turns it into ``jax.ShapeDtypeStruct``s (shapes for building and
+lowering a step without allocating); ``axes_tree`` extracts the logical
 axes used by ``dist.sharding`` to build NamedShardings.
 """
 
@@ -87,7 +87,7 @@ def materialize(specs, key: jax.Array):
 
 
 def abstract(specs):
-    """PSpec tree -> ShapeDtypeStruct tree (no allocation; dry-run path)."""
+    """PSpec tree -> ShapeDtypeStruct tree (no allocation)."""
     return jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype), specs, is_leaf=is_pspec
     )

@@ -9,7 +9,7 @@ run unrolled as the "tail".
 Public surface:
     model_specs(cfg)                 -> PSpec tree (params, never allocated)
     init_params(cfg, key)            -> concrete params
-    abstract_params(cfg)             -> ShapeDtypeStruct tree (dry-run)
+    abstract_params(cfg)             -> ShapeDtypeStruct tree (no allocation)
     param_axes(cfg)                  -> logical-axes tree
     param_counts(cfg)                -> (total, active) parameter counts
     cache_specs(cfg, batch, max_seq) -> PSpec tree (decode cache)
@@ -244,13 +244,7 @@ def _apply_stack(
 
 
 def _embed_tokens(cfg, params, tokens):
-    table = params["embed"]["tokens"]
-    if cfg.embed_gather_constraint:
-        # pre-reshard: keep vocab sharded, gather the (FSDP-sharded) embed dim
-        # first — avoids SPMD "involuntary full rematerialization" of the
-        # token gather (EXPERIMENTS.md §Perf H3)
-        table = constrain(table, "vocab", None)
-    x = table[tokens]
+    x = params["embed"]["tokens"][tokens]
     return constrain(x, "batch", "seq", None)
 
 
@@ -265,15 +259,9 @@ def _build_inputs(cfg, params, batch):
 
 def _logits(cfg, params, h):
     if cfg.tied_embeddings:
-        table = params["embed"]["tokens"]
-        if cfg.embed_gather_constraint:
-            table = constrain(table, "vocab", None)
-        logits = jnp.einsum("bsd,vd->bsv", h, table)
+        logits = jnp.einsum("bsd,vd->bsv", h, params["embed"]["tokens"])
     else:
-        head = params["lm_head"]
-        if cfg.embed_gather_constraint:
-            head = constrain(head, None, "vocab")
-        logits = jnp.einsum("bsd,dv->bsv", h, head)
+        logits = jnp.einsum("bsd,dv->bsv", h, params["lm_head"])
     return constrain(logits, "batch", "seq", "vocab")
 
 
@@ -331,59 +319,12 @@ def _ce_terms(cfg, logits, targets, z_coef):
     )
 
 
-def _hidden_for_loss(cfg: ArchConfig, params, batch):
-    """Forward up to the final norm; returns (h_text, aux). h_text aligns with
-    ``targets`` (vlm prefixes already rebased, like forward's logit slice)."""
-    pattern = decoder_pattern(cfg)
-    enc_out = None
-    if cfg.family == "encdec":
-        enc_out = _encode(cfg, params, batch)
-    h, n_prefix = _build_inputs(cfg, params, batch)
-    s = h.shape[1]
-    rope = rotary_embedding(jnp.arange(s), cfg.resolved_head_dim, cfg.rope_theta)
-    h, _, aux = _apply_stack(
-        cfg, params, h, kinds_pattern=pattern, scan_key="scan", tail_key="tail",
-        rope=rope, mode="train", enc_out=enc_out,
-    )
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    if n_prefix:
-        st = batch["tokens"].shape[1]
-        h = h[:, n_prefix - 1 : n_prefix - 1 + st]
-    return h, aux
-
-
 def loss_fn(cfg: ArchConfig, params, batch, aux_coef: float = 0.01, z_coef: float = 1e-4):
-    targets = batch["targets"]
-    chunk = cfg.loss_chunk
-    if chunk and targets.shape[1] % chunk == 0 and targets.shape[1] > chunk:
-        # §Perf H3: chunked cross-entropy — the (B,S,V) logits tensor never
-        # materializes; each seq chunk computes logits+CE under remat.
-        h, aux = _hidden_for_loss(cfg, params, batch)
-        b, s, d = h.shape
-        n = s // chunk
-        hc = jnp.moveaxis(h.reshape(b, n, chunk, d), 1, 0)
-        tc = jnp.moveaxis(targets.reshape(b, n, chunk), 1, 0)
-
-        @jax.checkpoint
-        def body(carry, xs):
-            hb, tb = xs
-            logits = _logits(cfg, params, hb)
-            ce_s, z_s, v_s = _ce_terms(cfg, logits, tb, z_coef)
-            c0, c1, c2 = carry
-            return (c0 + ce_s, c1 + z_s, c2 + v_s), None
-
-        (ce_sum, z_sum, n_valid), _ = jax.lax.scan(
-            body, (jnp.zeros(()), jnp.zeros(()), jnp.zeros(())), (hc, tc)
-        )
-        n_valid = jnp.maximum(n_valid, 1.0)
-        ce = ce_sum / n_valid
-        z_loss = z_sum / n_valid
-    else:
-        logits, aux = forward(cfg, params, batch)
-        ce_sum, z_sum, n_valid = _ce_terms(cfg, logits, targets, z_coef)
-        n_valid = jnp.maximum(n_valid, 1.0)
-        ce = ce_sum / n_valid
-        z_loss = z_sum / n_valid
+    logits, aux = forward(cfg, params, batch)
+    ce_sum, z_sum, n_valid = _ce_terms(cfg, logits, batch["targets"], z_coef)
+    n_valid = jnp.maximum(n_valid, 1.0)
+    ce = ce_sum / n_valid
+    z_loss = z_sum / n_valid
     loss = ce + z_loss + aux_coef * aux
     metrics = {"loss": loss, "ce": ce, "aux": aux, "z_loss": z_loss, "tokens": n_valid}
     return loss, metrics

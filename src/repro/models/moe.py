@@ -1,6 +1,6 @@
 """Mixture-of-Experts FFN: top-k token-choice routing with static capacity.
 
-Expert-parallel design (DESIGN.md §3): expert-stacked weights carry the
+Expert-parallel design: expert-stacked weights carry the
 ``expert`` logical axis (sharded over the ``model`` mesh axis), and dispatch is
 gather/scatter-based — tokens are packed into an (E, C) slot buffer with
 ``take``/scatter-add, *not* with GShard's dense one-hot dispatch einsums, so
@@ -64,8 +64,8 @@ def _moe_grouped(
     """Dispatch/combine are written per-row and ``vmap``ed over the batch, so
     every scatter/gather carries the batch as an *operand batch dimension* —
     SPMD partitions those along the (sharded) batch axis instead of replicating
-    the full global buffer (§Perf H1: advanced-indexing scatters with explicit
-    batch index arrays forced "involuntary full rematerialization" + 30 GB
+    the full global buffer (advanced-indexing scatters with explicit batch
+    index arrays force SPMD's "involuntary full rematerialization" and
     all-reduces of replicated (B_global, S, D) buffers)."""
     moe = cfg.moe
     b, s, d = x.shape
@@ -100,24 +100,18 @@ def _moe_grouped(
         return xe, token_idx, w_slot, mask
 
     xe, token_idx, w_slot, expert_mask = jax.vmap(route_row)(x, top_v, top_i)
-    if cfg.moe_dispatch_mode == "tokens":
-        xe = constrain(xe, "batch", "expert", None, None)
-    else:
-        xe = constrain(xe, "batch", "expert", None, "embed")
+    xe = constrain(xe, "batch", "expert", None, "embed")
 
     up = jnp.einsum("becd,edf->becf", xe, p["wi"])
     gate = jnp.einsum("becd,edf->becf", xe, p["wg"])
     hidden = jax.nn.silu(gate.astype(jnp.float32)).astype(xe.dtype) * up
     ye = jnp.einsum("becf,efd->becd", hidden, p["wo"])
-    if cfg.moe_dispatch_mode == "tokens":
-        ye = constrain(ye, "batch", "expert", None, None)
-    else:
-        ye = constrain(ye, "batch", "expert", None, "embed")
+    ye = constrain(ye, "batch", "expert", None, "embed")
 
     def combine_row(ye_r: jax.Array, ti_r: jax.Array, ws_r: jax.Array):
         # accumulate in the activation dtype: each token receives ≤ top_k adds
         # (distinct slots), and the EP combine all-reduce over the model axis
-        # moves half the bytes vs f32 (§Perf H1 iter 3)
+        # moves half the bytes vs f32
         y_pad = jnp.zeros((s + 1, d), x.dtype)
         y_pad = y_pad.at[ti_r].add((ye_r.astype(jnp.float32) * ws_r[..., None]).astype(x.dtype))
         return y_pad[:s]

@@ -19,8 +19,7 @@ Constraints checked by :func:`check_pipelineable`:
   * dense blocks (``cfg.moe is None`` — the MoE aux loss is not plumbed
     through the per-stage loss accumulation),
   * ``n_layers`` divisible by the pattern length (no unrolled tail — every
-    slot runs the same unit function),
-  * ``cfg.loss_chunk`` unused here (the head sees one microbatch at a time).
+    slot runs the same unit function).
 """
 
 from __future__ import annotations

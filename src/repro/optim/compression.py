@@ -12,8 +12,7 @@ Two pieces:
   payload + f32 scales across pods and reduce locally.  For 2 pods this moves
   ~1 byte/param over the pod links instead of ~4 (bf16 ring all-reduce moves
   2·2 bytes/param), a ~4× collective-bytes cut on the slowest (inter-pod)
-  links.  The dry-run variant records the HLO collective-bytes delta in
-  EXPERIMENTS.md §Perf.
+  links.
 """
 
 from __future__ import annotations

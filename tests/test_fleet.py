@@ -179,16 +179,14 @@ def test_data_parallel_rank_dense_and_stable():
 
 # --- payback ------------------------------------------------------------------
 
-def test_reshard_cost_from_baseline_and_fallback(tmp_path):
-    cost = ReshardCost.from_baseline()  # committed baseline: measured values
-    assert 0.0 < cost.save_s < 1.0 and 0.0 < cost.restore_s < 1.0
-    missing = ReshardCost.from_baseline(str(tmp_path / "nope.json"))
-    assert missing.save_s == ReshardCost().save_s  # conservative fallback
-    custom = tmp_path / "b.json"
-    custom.write_text(json.dumps({
-        "rows": [{"name": "ckpt/save_sync", "us_per_call": 2_000_000.0}]
-    }))
-    assert ReshardCost.from_baseline(str(custom)).save_s == pytest.approx(2.0)
+def test_reshard_cost_starts_at_fallback_and_observe_moves_it():
+    cost = ReshardCost()
+    assert cost.save_s == pytest.approx(0.006)
+    assert cost.restore_s == pytest.approx(0.003)
+    assert cost.total() == pytest.approx(0.009)
+    cost.observe(save_s=0.106, restore_s=0.203)  # ewma 0.5
+    assert cost.save_s == pytest.approx(0.056)
+    assert cost.restore_s == pytest.approx(0.103)
 
 
 def test_reshard_cost_observe_ewma():

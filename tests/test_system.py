@@ -113,8 +113,8 @@ def test_adaptive_bound_respected_with_slow_ckpt(tmp_path):
     # controller must suppress and end up well below the every-step baseline
     assert adaptive["checkpoint"]["n_suppressed"] > 0
     assert adaptive["checkpoint"]["n_checkpoints"] < fixed["checkpoint"]["n_checkpoints"]
-    # proper bound adherence over long horizons is validated in
-    # benchmarks/bench_adaptive_checkpoint.py (Fig. 3 reproduction)
+    # bound adherence over long horizons is what
+    # examples/amr_adaptive_checkpoint.py prints (Fig. 3 reproduction)
 
 
 def test_serving_engine_completes_and_steers():
