@@ -23,10 +23,15 @@ def default_interpret(interpret: bool | None) -> bool:
     return current_platform() != "tpu"
 
 
-def tpu_compiler_params(dimension_semantics: Sequence[str], interpret: bool):
-    """CompilerParams with dimension semantics (None in interpret mode)."""
+def tpu_compiler_params(
+    dimension_semantics: Sequence[str], interpret: bool, vmem_limit_bytes: int | None = None
+):
+    """CompilerParams with dimension semantics and, where given, a scoped VMEM
+    limit other than the chip's default (None in interpret mode)."""
     if interpret:
         return None
     from jax.experimental.pallas import tpu as pltpu
 
-    return pltpu.CompilerParams(dimension_semantics=tuple(dimension_semantics))
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(dimension_semantics), vmem_limit_bytes=vmem_limit_bytes
+    )

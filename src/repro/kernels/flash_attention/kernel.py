@@ -1,4 +1,4 @@
-"""Flash attention TPU kernels (Pallas): forward + backward (dq, dkv).
+"""Flash attention TPU kernels (Pallas): a forward and one backward kernel.
 
 TPU adaptation: the online-softmax accumulators (m, l, acc)
 live in VMEM scratch that persists across the *sequential* last grid dimension
@@ -18,12 +18,17 @@ the model's (B, S, H, hd).  The per-row softmax statistics ``lse`` and
 ``delta`` are kept as (B, H, 1, S) so that their (1, block_q) tiles lie along
 the lane axis: Mosaic requires the last two block dims to be (8, 128)-aligned
 or whole, and a (block_q,) trailing block is neither.  GQA is handled by
-indexing the KV head as ``h // group`` in the BlockSpec index maps; the dkv
-kernel walks a K/V group's query heads inside the grid and accumulates their
-dk/dv into one K/V block.  Causal and sliding-window masks are supported:
-tiles outside the mask's support are skipped with ``pl.when`` and fetch
-nothing (their index maps repeat the nearest needed block), and only tiles
-that straddle its edge are masked.
+indexing the KV head as ``h // group`` in the BlockSpec index maps.
+
+The backward forms each tile's scores, probabilities and dP once and feeds
+dV, dK and dQ from them (5 matmuls and one exp a tile).  Its grid walks a
+K/V head's query heads and their q blocks, with the key blocks innermost:
+dq sums over the key blocks in a (block_q, hd) scratch, and dk/dv sum over
+the group's query heads and q blocks in (T, hd) scratch holding the K/V
+head's whole sequence, written out once after its last tile.  Causal and
+sliding-window masks are supported: tiles outside the mask's support are
+skipped with ``pl.when`` and fetch nothing (their index maps repeat the
+nearest needed block), and only tiles that straddle its edge are masked.
 """
 
 from __future__ import annotations
@@ -37,15 +42,23 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..common import tpu_compiler_params
 
-__all__ = ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]
+__all__ = ["flash_fwd", "flash_bwd"]
 
 _NEG_INF = -2.0e38
+#: Mosaic's scoped VMEM limit by default on a v5e
+_VMEM_DEFAULT = 16 * 2**20
 #: contract the last dims of both operands: a @ b.T without a transpose
 _NT = (((1,), (1,)), ((), ()))
+#: contract the first dims of both operands: a.T @ b
+_TN = (((0,), (0,)), ((), ()))
 
 
 def _dot_nt(a, b):
     return jax.lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32)
+
+
+def _dot_tn(a, b):
+    return jax.lax.dot_general(a, b, _TN, preferred_element_type=jnp.float32)
 
 
 def _dot(a, b):
@@ -104,16 +117,6 @@ def _k_range(iq, block_q, block_k, n_k, causal, window):
         lo = jnp.maximum(0, (iq * block_q - window + 1) // block_k)
     if causal:
         hi = jnp.minimum(n_k - 1, (iq * block_q + block_q - 1) // block_k)
-    return lo, hi
-
-
-def _q_range(ik, block_q, block_k, n_q, causal, window):
-    """First and last query block that key block ``ik`` is needed by."""
-    lo, hi = 0, n_q - 1
-    if causal:
-        lo = jnp.minimum(n_q - 1, ik * block_k // block_q)
-    if window is not None:
-        hi = jnp.minimum(n_q - 1, (ik * block_k + block_k + window - 2) // block_q)
     return lo, hi
 
 
@@ -221,143 +224,80 @@ def flash_fwd(
 
 
 # ---------------------------------------------------------------------------
-# Backward: dq (grid over q blocks, scan k blocks)
+# Backward: one kernel for dq, dk and dv (grid over a K/V head's query heads
+# and their q blocks, scan the key blocks each q block needs).  Scores are
+# formed transposed, (block_k, block_q), so the (1, block_q) lse/delta tiles
+# broadcast along sublanes as they are loaded.
 # ---------------------------------------------------------------------------
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, lse_scr, delta_scr,
-               *, scale, block_q, block_k, n_k, causal, window):
-    iq, ik = pl.program_id(2), pl.program_id(3)
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+                dq_scr, dk_scr, dv_scr,
+                *, scale, block_q, block_k, group, n_q, n_k, causal, window):
+    ig, iq, ik = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+    kv_rows = pl.ds(pl.multiple_of(ik * block_k, block_k), block_k)
 
-    @pl.when(ik == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
-        lse_scr[...] = lse_ref[0, 0].T  # (1, bq) -> (bq, 1), once per q block
-        delta_scr[...] = delta_ref[0, 0].T
-
-    def compute(masked):
-        kb = k_ref[0, 0]
-        s = _dot_nt(q_ref[0, 0], kb) * scale  # (bq, bk)
-        if masked:
-            ok = _visible(s.shape, iq * block_q, ik * block_k, causal, window, q_axis=0)
-            s = jnp.where(ok, s, _NEG_INF)
-        p = jnp.exp(s - lse_scr[...])
-        dp = _dot_nt(do_ref[0, 0], v_ref[0, 0])
-        ds = p * (dp - delta_scr[...])
-        dq_scr[...] += _dot(ds.astype(kb.dtype), kb)
-
-    _for_tile(compute, iq, ik, block_q, block_k, causal, window)
-
-    @pl.when(ik == n_k - 1)
-    def _finish():
-        dq_ref[0, 0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
-
-
-def flash_bwd_dq(q, k, v, do, lse, delta, *, causal, window, block_q, block_k, interpret):
-    b, h, s, hd = q.shape
-    kv, t = k.shape[1], k.shape[2]
-    group = h // kv
-    n_q, n_k = s // block_q, t // block_k
-    kernel = functools.partial(
-        _dq_kernel, scale=hd ** -0.5, block_q=block_q, block_k=block_k,
-        n_k=n_k, causal=causal, window=window,
-    )
-
-    def q_map(bi, hi, qi, ki):
-        return (bi, hi, qi, 0)
-
-    kv_map = _kv_index_map(group, block_q, block_k, n_k, causal, window)
-
-    def row_map(bi, hi, qi, ki):
-        return (bi, hi, 0, qi)
-
-    return pl.pallas_call(
-        kernel,
-        grid=(b, h, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, hd), q_map),
-            pl.BlockSpec((1, 1, block_k, hd), kv_map),
-            pl.BlockSpec((1, 1, block_k, hd), kv_map),
-            pl.BlockSpec((1, 1, block_q, hd), q_map),
-            pl.BlockSpec((1, 1, 1, block_q), row_map),
-            pl.BlockSpec((1, 1, 1, block_q), row_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, hd), q_map),
-        out_shape=jax.ShapeDtypeStruct((b, h, s, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, hd), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-        ],
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "parallel", "arbitrary"), interpret
-        ),
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
-
-
-# ---------------------------------------------------------------------------
-# Backward: dk/dv (grid over a K/V head's k blocks, scan its query heads and
-# their q blocks).  Scores are formed transposed, (block_k, block_q), so the
-# (1, block_q) lse/delta tiles broadcast along sublanes as they are loaded.
-# ---------------------------------------------------------------------------
-
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                dk_scr, dv_scr, *, scale, block_q, block_k, group, n_q, causal, window):
-    ik, ig, iq = pl.program_id(2), pl.program_id(3), pl.program_id(4)
-
-    @pl.when((ig == 0) & (iq == 0))
-    def _init():
+    @pl.when((ig == 0) & (iq == 0) & (ik == 0))
+    def _init_kv():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
+    @pl.when(ik == 0)
+    def _init_q():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
     def compute(masked):
-        q, do = q_ref[0, 0], do_ref[0, 0]
-        s_t = _dot_nt(k_ref[0, 0], q) * scale  # (bk, bq)
+        q, k, do = q_ref[0, 0], k_ref[0, 0], do_ref[0, 0]
+        s_t = _dot_nt(k, q) * scale  # (bk, bq)
         if masked:
             ok = _visible(s_t.shape, iq * block_q, ik * block_k, causal, window, q_axis=1)
             s_t = jnp.where(ok, s_t, _NEG_INF)
         p_t = jnp.exp(s_t - lse_ref[0, 0])
-        dv_scr[...] += _dot(p_t.astype(do.dtype), do)
         dp_t = _dot_nt(v_ref[0, 0], do)
-        ds_t = p_t * (dp_t - delta_ref[0, 0])
-        dk_scr[...] += _dot(ds_t.astype(q.dtype), q)
+        ds_t = (p_t * (dp_t - delta_ref[0, 0])).astype(q.dtype)
+        dv_scr[kv_rows] += _dot(p_t.astype(do.dtype), do)
+        dk_scr[kv_rows] += _dot(ds_t, q)
+        dq_scr[...] += _dot_tn(ds_t, k)
 
     _for_tile(compute, iq, ik, block_q, block_k, causal, window)
 
-    @pl.when((ig == group - 1) & (iq == n_q - 1))
-    def _finish():
+    @pl.when(ik == n_k - 1)
+    def _finish_q():
+        dq_ref[0, 0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
+
+    @pl.when((ig == group - 1) & (iq == n_q - 1) & (ik == n_k - 1))
+    def _finish_kv():
         dk_ref[0, 0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, window, block_q, block_k, interpret):
-    """Returns (dk, dv) of k's shape (B, KV, T, hd), summed over each K/V
-    head's query heads."""
+def flash_bwd(q, k, v, do, lse, delta, *, causal, window, block_q, block_k, interpret):
+    """Returns (dq, dk, dv): dq of q's shape (B, H, S, hd), dk and dv of k's
+    shape (B, KV, T, hd), summed over each K/V head's query heads."""
     b, h, s, hd = q.shape
     kv, t = k.shape[1], k.shape[2]
     group = h // kv
     n_q, n_k = s // block_q, t // block_k
     kernel = functools.partial(
-        _dkv_kernel, scale=hd ** -0.5, block_q=block_q, block_k=block_k,
-        group=group, n_q=n_q, causal=causal, window=window,
+        _bwd_kernel, scale=hd ** -0.5, block_q=block_q, block_k=block_k,
+        group=group, n_q=n_q, n_k=n_k, causal=causal, window=window,
     )
+    kv_block = _kv_index_map(group, block_q, block_k, n_k, causal, window)
 
-    def q_block(ki, qi):
-        return _clamp(qi, *_q_range(ki, block_q, block_k, n_q, causal, window))
+    def q_map(bi, kvi, gi, qi, ki):
+        return (bi, kvi * group + gi, qi, 0)
 
-    def q_map(bi, kvi, ki, gi, qi):
-        return (bi, kvi * group + gi, q_block(ki, qi), 0)
+    def row_map(bi, kvi, gi, qi, ki):
+        return (bi, kvi * group + gi, 0, qi)
 
-    def row_map(bi, kvi, ki, gi, qi):
-        return (bi, kvi * group + gi, 0, q_block(ki, qi))
+    def kv_map(bi, kvi, gi, qi, ki):
+        return kv_block(bi, kvi * group + gi, qi, ki)
 
-    def kv_map(bi, kvi, ki, gi, qi):
-        return (bi, kvi, ki, 0)
+    def kv_whole(bi, kvi, gi, qi, ki):
+        return (bi, kvi, 0, 0)
 
     return pl.pallas_call(
         kernel,
-        grid=(b, kv, n_k, group, n_q),
+        grid=(b, kv, group, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, hd), q_map),
             pl.BlockSpec((1, 1, block_k, hd), kv_map),
@@ -367,19 +307,24 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, *, causal, window, block_q, block_k, 
             pl.BlockSpec((1, 1, 1, block_q), row_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_k, hd), kv_map),
-            pl.BlockSpec((1, 1, block_k, hd), kv_map),
+            pl.BlockSpec((1, 1, block_q, hd), q_map),
+            pl.BlockSpec((1, 1, t, hd), kv_whole),
+            pl.BlockSpec((1, 1, t, hd), kv_whole),
         ],
         out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, hd), jnp.float32),
-            pltpu.VMEM((block_k, hd), jnp.float32),
+            pltpu.VMEM((block_q, hd), jnp.float32),
+            pltpu.VMEM((t, hd), jnp.float32),
+            pltpu.VMEM((t, hd), jnp.float32),
         ],
         compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "parallel", "arbitrary", "arbitrary"), interpret
+            ("parallel", "parallel", "arbitrary", "arbitrary", "arbitrary"), interpret,
+            # the (T, hd) dk/dv accumulators and their two-buffered output blocks
+            vmem_limit_bytes=_VMEM_DEFAULT + 2 * t * hd * (4 + 2 * k.dtype.itemsize),
         ),
         interpret=interpret,
     )(q, k, v, do, lse, delta)

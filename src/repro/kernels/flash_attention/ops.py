@@ -2,7 +2,8 @@
 
 Public entry ``flash_attention(q, k, v, causal=..., window=...)`` takes the
 model layout (B, S, H, hd) / (B, T, KV, hd), transposes to kernel layout,
-runs the Pallas forward, and differentiates through the dq/dkv Pallas kernels.
+runs the Pallas forward, and differentiates through one Pallas backward
+kernel that returns dq, dk and dv.
 Block sizes come from the shape (:func:`block_sizes`), as tiles the TPU
 accepts; a sequence length that has none raises instead of silently running
 another attention implementation.
@@ -83,15 +84,10 @@ def _flash_fwd_rule(q, k, v, causal, window, block_q, block_k, interpret):
 def _flash_bwd_rule(causal, window, block_q, block_k, interpret, res, do):
     q, k, v, out, lse = res
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)[:, :, None]
-    dq = K.flash_bwd_dq(
+    return K.flash_bwd(
         q, k, v, do, lse, delta, causal=causal, window=window,
         block_q=block_q, block_k=block_k, interpret=interpret,
     )
-    dk, dv = K.flash_bwd_dkv(
-        q, k, v, do, lse, delta, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, interpret=interpret,
-    )
-    return dq, dk, dv
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)

@@ -63,7 +63,8 @@ def test_flash_gradients_match_ref(causal, window):
 
 
 #: seq 512 cut into tiles of 128 and 256, so the masks skip some tiles, keep
-#: others whole and cut across the rest, and the index maps clamp
+#: others whole and cut across the rest, and the index maps clamp; the last
+#: three at the train cell's head of 128 and 8 query heads per K/V head
 TILED = [
     # (S, T, H, KV, hd, causal, window, block_q, block_k)
     (512, 512, 4, 2, 64, True, None, 128, 128),     # causal
@@ -72,6 +73,10 @@ TILED = [
     (512, 512, 8, 2, 64, True, 64, 256, 128),       # window inside one q tile
     (512, 512, 4, 2, 64, False, None, 128, 256),    # no mask
     (256, 512, 4, 2, 64, False, None, 128, 128),    # cross-shape
+    # dq sums over 4 key blocks, dk/dv over 8 query heads of one K/V head
+    (512, 512, 16, 2, 128, True, None, 128, 128),   # causal
+    (512, 512, 16, 2, 128, True, 300, 128, 128),    # window off the tile grid
+    (256, 512, 16, 2, 128, False, None, 128, 128),  # cross-shape, no mask
 ]
 
 

@@ -59,6 +59,12 @@ def _assert_mosaic(fn, *args):
     assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _kernel_count(fn, *args):
+    """How many Pallas kernels the compiled program calls."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
 def _sds(one_chip, shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
@@ -80,13 +86,23 @@ def test_flash_attention_compiles_fwd_and_bwd(arch, one_chip):
 
 def test_flash_attention_compiles_at_the_train_cell(one_chip):
     """GLM-4-9B's attention as the train cell runs it on one v5e: 1 x 4096,
-    32 query heads over 2 K/V heads of 128, with the blocks the shape picks."""
+    32 query heads over 2 K/V heads of 128, with the blocks the shape picks;
+    its gradient runs two kernels, the forward and the one backward."""
     assert block_sizes(4096, 4096, 128) == (1024, 1024)
     fn = functools.partial(flash_attention, causal=True, interpret=False)
     q = _sds(one_chip, (1, 4096, 32, 128))
     k = _sds(one_chip, (1, 4096, 2, 128))
     _assert_mosaic(fn, q, k, k)
-    _assert_mosaic(_grad_of_sum(fn, (0, 1, 2)), q, k, k)
+    assert _kernel_count(_grad_of_sum(fn, (0, 1, 2)), q, k, k) == 2
+
+
+def test_flash_attention_backward_fits_vmem_at_seq_16k(one_chip):
+    """The backward keeps a K/V head's whole dk/dv in VMEM: at 1 x 16384,
+    32/2 heads of 128, it still compiles within the chip's VMEM."""
+    fn = functools.partial(flash_attention, causal=True, interpret=False)
+    q = _sds(one_chip, (1, 16384, 32, 128))
+    k = _sds(one_chip, (1, 16384, 2, 128))
+    assert _kernel_count(_grad_of_sum(fn, (0, 1, 2)), q, k, k) == 2
 
 
 @pytest.mark.parametrize("arch", ["llama3.2-1b", "recurrentgemma-9b", "rwkv6-1.6b"])
